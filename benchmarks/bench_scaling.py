@@ -2,6 +2,10 @@
 parallel-resource axis is host devices; we run the distributed medium-grained
 CP-ALS MTTKRP path over 1/2/4/8 host devices in subprocesses and report the
 per-iteration wall time (near-linear scaling is the paper's claim).
+
+A CPU simulation by design: the children are pinned to the CPU backend, so
+on a TPU host they neither see one chip in place of the simulated mesh nor
+contend for it.
 """
 from __future__ import annotations
 
@@ -19,8 +23,9 @@ import time, json
 import jax, jax.numpy as jnp
 from repro.core import random_sparse
 from repro.core.distributed import dist_cp_als
+from repro.dist.collectives import make_mesh
 n = {n}
-mesh = jax.make_mesh(({rows}, {cols}), ("data", "model"))
+mesh = make_mesh(({rows}, {cols}), ("data", "model"))
 t = random_sparse((3000, 2500, 2000), 150_000, jax.random.PRNGKey(0))
 t0 = time.time()
 dist_cp_als(t, 16, mesh, niters=1)   # compile+first
@@ -36,7 +41,7 @@ def run():
     root = Path(__file__).resolve().parents[1]
     base = None
     for n, (r, c) in ((1, (1, 1)), (2, (2, 1)), (4, (2, 2)), (8, (4, 2))):
-        env = dict(os.environ,
+        env = dict(os.environ, JAX_PLATFORMS="cpu",
                    XLA_FLAGS=f"--xla_force_host_platform_device_count={n}",
                    PYTHONPATH=str(root / "src"))
         code = textwrap.dedent(_CHILD.format(n=n, rows=r, cols=c))

@@ -445,7 +445,9 @@ def cmd_ratchet(args) -> int:
 def cmd_dryrun(args) -> int:
     """Compile-matrix dry-run.  Re-execs ``repro.launch.dryrun`` in a fresh
     interpreter: the 512-placeholder-device XLA_FLAGS must be set before jax
-    initializes, and this process has already imported jax."""
+    initializes, and this process has already imported jax.  The child is a
+    CPU simulation, pinned to the CPU backend."""
+    import os
     import subprocess
 
     cmd = [sys.executable, "-m", "repro.launch.dryrun",
@@ -454,7 +456,7 @@ def cmd_dryrun(args) -> int:
         cmd += ["--tag", args.tag]
     for ov in args.override:
         cmd += ["--override", ov]
-    return subprocess.call(cmd)
+    return subprocess.call(cmd, env={**os.environ, "JAX_PLATFORMS": "cpu"})
 
 
 def main(argv: Optional[Sequence[str]] = None) -> int:
@@ -545,6 +547,9 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     if args.command is None:
         ap.print_help()
         return 2
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     try:
         return args.fn(args)
     except (ConfigError, ValueError, OSError) as e:

@@ -31,12 +31,11 @@ from typing import Sequence
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.obs import trace as obs_trace
 from repro.dist.collectives import (cpals_axes, gather_rows, pgram,
-                                    pnormalize_columns, scatter_rows,
-                                    shard_map)
+                                    pnormalize_columns, scatter_rows)
 
 from .coo import SparseTensor
 from .gram import (column_norms, gram, hadamard_grams, kruskal_fit,
@@ -57,9 +56,10 @@ DIST_IMPLS = ("gather_scatter", "segment")
 def partition_tensor(t: SparseTensor, n_row: int, n_col: int,
                      *, pad_factor: float = 1.05):
     """Partition non-zeros over an (n_row x n_col) grid by (mode-0 block,
-    mode-1 block).  Returns (inds (n_row, n_col, L, 3), vals (n_row, n_col, L),
-    padded dims).  Padding entries have val 0 and point at the block's first
-    local rows."""
+    mode-1 block).  Returns host arrays (inds (n_row, n_col, L, 3), vals
+    (n_row, n_col, L)) and the padded dims.  Within a grid block the
+    non-zeros keep their input order; padding entries have val 0 and point
+    at the block's first local rows."""
     assert t.order == 3, "medium-grained partitioner is 3rd-order (like SPLATT)"
     inds = np.asarray(t.inds[: t.nnz])
     vals = np.asarray(t.vals[: t.nnz])
@@ -81,16 +81,14 @@ def partition_tensor(t: SparseTensor, n_row: int, n_col: int,
     for c in range(n_col):
         out_i[:, c, :, 1] = c * bj
 
-    fill = np.zeros((n_row, n_col), dtype=np.int64)
+    # stable sort by grid block, then each entry's rank inside its block
     order = np.lexsort((dj, di))
-    for idx in order:
-        r, c = di[idx], dj[idx]
-        k = fill[r, c]
-        out_i[r, c, k] = inds[idx]
-        out_v[r, c, k] = vals[idx]
-        fill[r, c] += 1
-
-    return jnp.asarray(out_i), jnp.asarray(out_v), (i_p, j_p, t.dims[2])
+    r, c = di[order], dj[order]
+    block_start = np.concatenate([[0], np.cumsum(counts.ravel())[:-1]])
+    k = np.arange(order.shape[0]) - block_start[r * n_col + c]
+    out_i[r, c, k] = inds[order]
+    out_v[r, c, k] = vals[order]
+    return out_i, out_v, (i_p, j_p, t.dims[2])
 
 
 # ---------------------------------------------------------------------------
@@ -184,7 +182,7 @@ def make_dist_iteration(mesh: Mesh, dims_p, rank: int, *, norm_kind: str = "2",
             if shard_c:
                 gc = pgram(c_in, all_ax)
             else:
-                gc = c.T @ c
+                gc = gram(c)
             return ga, gb, gc
 
         ga, gb, gc = grams_all(a_blk, b_blk, c_full)
@@ -231,13 +229,13 @@ def make_dist_iteration(mesh: Mesh, dims_p, rank: int, *, norm_kind: str = "2",
         lam_c = column_norms(c_new, kind=norm_kind)
         safe = jnp.where(lam_c == 0.0, 1.0, lam_c)
         c_new, lam = c_new / safe[None, :], lam_c
-        gc = c_new.T @ c_new
+        gc = gram(c_new)
 
         fit = kruskal_fit(norm_x_sq, lam, (ga, gb, gc), m2, c_new)
         return a_new, b_new, c_new, lam, fit
 
-    smapped = shard_map(body, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs)
+    smapped = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                            out_specs=out_specs)
     return jax.jit(smapped)
 
 
@@ -343,6 +341,11 @@ def dist_cp_als(t: SparseTensor, rank: int, mesh: Mesh, *, niters: int = 10,
     n_row, n_col, n_all = ax.n_row, ax.n_col, ax.n_all
 
     inds, vals, dims_p = partition_tensor(t, n_row, n_col)
+    # place every input under the spec the iteration consumes it with, so
+    # each device holds its own share from the start
+    shard = lambda x, spec: jax.device_put(x, NamedSharding(mesh, spec))
+    inds = shard(inds, ax.grid_spec())
+    vals = shard(vals, ax.grid_spec())
     i_p, j_p, k_dim = dims_p
     if shard_c:
         k_dim = -(-k_dim // n_all) * n_all
@@ -356,9 +359,10 @@ def dist_cp_als(t: SparseTensor, rank: int, mesh: Mesh, *, niters: int = 10,
     else:
         full = init_factors((i_p, j_p, k_dim), rank, key, dtype=t.vals.dtype)
     # zero padded factor rows so grams match the unpadded computation
-    a0 = full[0].at[t.dims[0]:].set(0.0)
-    b0 = full[1].at[t.dims[1]:].set(0.0)
-    c0 = full[2].at[t.dims[2]:].set(0.0)
+    a0 = shard(full[0].at[t.dims[0]:].set(0.0), ax.row_spec())
+    b0 = shard(full[1].at[t.dims[1]:].set(0.0), ax.col_spec())
+    c0 = shard(full[2].at[t.dims[2]:].set(0.0),
+               ax.all_spec() if shard_c else P())
     norm_x_sq = jnp.sum(t.vals.astype(jnp.float32) ** 2)
 
     it_first = make_dist_iteration(mesh, dims_p, rank, norm_kind="max",
@@ -417,7 +421,6 @@ def build_dist_cpals_lowered(workload: str, mesh: Mesh, *,
     k_p = -(-dims[2] // n_all) * n_all if shard_c else dims[2]
     dims_p = (i_p, j_p, k_p)
 
-    from jax.sharding import NamedSharding
     sds = jax.ShapeDtypeStruct
     sh = lambda spec: NamedSharding(mesh, spec)
     inds = sds((n_row, n_col, cap, 3), jnp.int32, sharding=sh(ax.grid_spec()))
@@ -428,11 +431,9 @@ def build_dist_cpals_lowered(workload: str, mesh: Mesh, *,
     c = sds((k_p, rank), jnp.float32, sharding=sh(c_spec))
     nx = sds((), jnp.float32)
 
-    from repro.utils.roofline import CompatLowered
-
     fn = make_dist_iteration(mesh, dims_p, rank, shard_c=shard_c,
                              local_impls=local_impls)
-    lowered = CompatLowered(fn.lower(inds, vals, a, b, c, nx))
+    lowered = fn.lower(inds, vals, a, b, c, nx)
     # MTTKRP flops: ~5 R nnz per mode (2R gather-products, R scatter-add,
     # 2R for the Khatri-Rao partial) x 3 modes, plus small dense terms.
     info = {"workload": workload, "dims": dims, "nnz": nnz, "rank": rank,

@@ -12,6 +12,11 @@ These are the paper's non-MTTKRP routines from Table III:
 All matrices here are I x R or R x R with small R (paper uses R=35), so these
 are jnp-native; the Pallas syrk kernel (kernels/syrk_pallas.py) is an optional
 drop-in for ``gram`` on tall-skinny inputs.
+
+Their products run at ``Precision.HIGHEST``: a TPU's default precision
+rounds f32 matmul operands to bfloat16, which would move the fit of an f32
+decomposition away from its f32 reference.  At rank R they are a small
+share of a sweep next to the MTTKRP.
 """
 from __future__ import annotations
 
@@ -27,6 +32,8 @@ Array = jax.Array
 # nearly-rank-deficient iterates without changing converged results.
 CHOLESKY_RIDGE = 1e-12
 
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def gram(a: Array, *, impl: str = "jnp") -> Array:
     """G = A^T A (syrk analogue). impl='pallas' uses the blocked kernel."""
@@ -34,7 +41,11 @@ def gram(a: Array, *, impl: str = "jnp") -> Array:
         from repro.kernels import ops as kops
 
         return kops.syrk(a)
-    return a.T @ a
+    # contract dim 0 directly: with a materialized ``a.T`` the eager and
+    # the jitted product may round differently, and a resumed fit (grams
+    # recomputed eagerly) would drift from the uninterrupted one
+    return jax.lax.dot_general(a, a, (((0,), (0,)), ((), ())),
+                               precision=HIGHEST)
 
 
 def hadamard_grams(grams: Sequence[Array], skip_mode: int) -> Array:
@@ -76,7 +87,7 @@ def solve_gram(m_mat: Array, v: Array) -> Array:
     eye = jnp.eye(r, dtype=v.dtype)
     c = jax.scipy.linalg.cho_factor(v + CHOLESKY_RIDGE * eye, lower=False)
     v_inv = jax.scipy.linalg.cho_solve(c, eye)
-    return m_mat @ v_inv
+    return jnp.matmul(m_mat, v_inv, precision=HIGHEST)
 
 
 def column_norms(a: Array, *, kind: str) -> Array:

@@ -31,7 +31,7 @@ pieces stack on top of the core CP-ALS kernels.
 """
 from .collectives import (CPAxes, MODEL_AXIS, axis_product, batch_axes,
                           cpals_axes, gather_rows, make_mesh, pgram,
-                          pnormalize_columns, scatter_rows, shard_map)
+                          pnormalize_columns, scatter_rows)
 from .compress import (compress_grads_int8, decompress_grads_int8,
                        init_error_feedback)
 from .straggler import StragglerMonitor
@@ -39,7 +39,7 @@ from .straggler import StragglerMonitor
 __all__ = [
     "CPAxes", "MODEL_AXIS", "axis_product", "batch_axes", "cpals_axes",
     "gather_rows", "make_mesh", "pgram", "pnormalize_columns",
-    "scatter_rows", "shard_map",
+    "scatter_rows",
     "compress_grads_int8", "decompress_grads_int8", "init_error_feedback",
     "StragglerMonitor",
 ]

@@ -42,28 +42,15 @@ POD_AXIS = "pod"
 
 
 # ---------------------------------------------------------------------------
-# jax version portability
+# the mesh constructor
 # ---------------------------------------------------------------------------
 
-def shard_map(f, *, mesh: Mesh, in_specs, out_specs):
-    """``jax.shard_map`` where available (>= 0.6), else the experimental
-    spelling older releases ship.  All shard_map entry points in the repo
-    (distributed CP-ALS, expert-parallel MoE) route through here."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-
-
 def make_mesh(shape: Sequence[int], axes: Sequence[str]) -> Mesh:
-    """``jax.make_mesh`` with Auto axis types when the installed jax has
-    them (explicit-sharding releases), plain otherwise."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with Auto axis types.  Every mesh in the repo goes
+    through here: ``jax.make_mesh`` defaults to Explicit axes, under which
+    indexing a sharded array needs an explicit ``out_sharding``."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 # ---------------------------------------------------------------------------
@@ -135,8 +122,12 @@ def cpals_axes(mesh: Mesh) -> CPAxes:
 # ---------------------------------------------------------------------------
 
 def pgram(mat: Array, axis_names: AxisName) -> Array:
-    """Gram matrix of a row-sharded factor: psum of the local A^T A."""
-    return jax.lax.psum(mat.T @ mat, axis_names)
+    """Gram matrix of a row-sharded factor: psum of the local A^T A, at
+    f32 precision (``repro.core.gram`` says why)."""
+    return jax.lax.psum(
+        jax.lax.dot_general(mat, mat, (((0,), (0,)), ((), ())),
+                            precision=jax.lax.Precision.HIGHEST),
+        axis_names)
 
 
 def pnormalize_columns(mat: Array, axis_names: AxisName, *,

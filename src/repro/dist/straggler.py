@@ -62,6 +62,11 @@ class StragglerMonitor:
             self._strikes[host] = 0
         dq.append(float(seconds))
 
+    def times(self, host: int = 0) -> tuple[float, ...]:
+        """``host``'s recorded step times, oldest first (the last
+        ``window`` of them)."""
+        return tuple(self._times.get(host, ()))
+
     def means(self) -> Dict[int, float]:
         """Window mean per host, warmed-up hosts only."""
         return {h: sum(dq) / len(dq) for h, dq in self._times.items()

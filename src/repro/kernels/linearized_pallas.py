@@ -26,7 +26,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.linearized import decode_field
 
-from .mttkrp_pallas import LANE, _compiler_params
+from .mttkrp_pallas import LANE
 
 Array = jax.Array
 
@@ -43,11 +43,11 @@ def _kernel(tile_map_ref, hi_ref, lo_ref, vals_ref, brows_ref, crows_ref,
         out_ref[...] = jnp.zeros_like(out_ref)
 
     # in-kernel coordinate decode: static shift + mask on the packed words
-    rows = decode_field(hi_ref[0], lo_ref[0], offset, width)  # (BLOCK,) int32
+    rows = decode_field(hi_ref[0, 0], lo_ref[0, 0], offset, width)  # (BLOCK,)
 
     # fused Khatri-Rao partial product: (BLOCK, R)
     prod = (
-        vals_ref[0][:, None].astype(jnp.float32)
+        vals_ref[0, 0][:, None].astype(jnp.float32)
         * brows_ref[0].astype(jnp.float32)
         * crows_ref[0].astype(jnp.float32)
     )
@@ -58,14 +58,15 @@ def _kernel(tile_map_ref, hi_ref, lo_ref, vals_ref, brows_ref, crows_ref,
         == local[None, :]
     )
     out_ref[...] += jax.lax.dot(
-        sel.astype(jnp.float32), prod, preferred_element_type=jnp.float32
+        sel.astype(jnp.float32), prod, precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32
     )
 
 
 def mttkrp_lin_pallas_call(
-    hi: Array,          # (nblocks, BLOCK) uint32 high words, sorted stream
-    lo: Array,          # (nblocks, BLOCK) uint32 low words
-    vals: Array,        # (nblocks, BLOCK)
+    hi: Array,          # (nblocks, 1, BLOCK) uint32 high words, sorted stream
+    lo: Array,          # (nblocks, 1, BLOCK) uint32 low words
+    vals: Array,        # (nblocks, 1, BLOCK)
     brows: Array,       # (nblocks, BLOCK, RP) gathered factor rows
     crows: Array,       # (nblocks, BLOCK, RP) gathered (pre-multiplied for
                         #  order > 3) remaining factor rows
@@ -75,9 +76,9 @@ def mttkrp_lin_pallas_call(
     row_tile: int,
     offset: int,        # sort mode's bit field position in the packed index
     width: int,
-    interpret: bool = True,
+    interpret: bool,
 ) -> Array:
-    nblocks, block = hi.shape
+    nblocks, _, block = hi.shape
     rp = brows.shape[-1]
     if rp % LANE:
         raise ValueError(f"rank must be padded to {LANE}, got {rp}")
@@ -86,9 +87,10 @@ def mttkrp_lin_pallas_call(
         num_scalar_prefetch=1,
         grid=(nblocks,),
         in_specs=[
-            pl.BlockSpec((1, block), lambda b, tm: (b, 0)),
-            pl.BlockSpec((1, block), lambda b, tm: (b, 0)),
-            pl.BlockSpec((1, block), lambda b, tm: (b, 0)),
+            # (1, 1, block) row blocks: see mttkrp_pallas_call
+            pl.BlockSpec((1, 1, block), lambda b, tm: (b, 0, 0)),
+            pl.BlockSpec((1, 1, block), lambda b, tm: (b, 0, 0)),
+            pl.BlockSpec((1, 1, block), lambda b, tm: (b, 0, 0)),
             pl.BlockSpec((1, block, rp), lambda b, tm: (b, 0, 0)),
             pl.BlockSpec((1, block, rp), lambda b, tm: (b, 0, 0)),
         ],
@@ -100,7 +102,7 @@ def mttkrp_lin_pallas_call(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((num_row_tiles * row_tile, rp),
                                        jnp.float32),
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),  # sequential: accumulation
         ),
         interpret=interpret,

@@ -37,10 +37,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 spells CompilerParams "TPUCompilerParams"
-_compiler_params = getattr(pltpu, "CompilerParams",
-                           getattr(pltpu, "TPUCompilerParams", None))
-
 Array = jax.Array
 
 LANE = 128  # TPU lane width: rank is padded to a multiple of this
@@ -59,25 +55,28 @@ def _kernel(tile_map_ref, rows_ref, vals_ref, brows_ref, crows_ref, out_ref,
 
     # fused Khatri-Rao partial product: (BLOCK, R)
     prod = (
-        vals_ref[0][:, None].astype(jnp.float32)
+        vals_ref[0, 0][:, None].astype(jnp.float32)
         * brows_ref[0].astype(jnp.float32)
         * crows_ref[0].astype(jnp.float32)
     )
     # one-hot segment matrix: S[m, n] = (rows[n] == tile*row_tile + m)
-    local = rows_ref[0] - tile * row_tile  # (BLOCK,), in [0, row_tile)
+    local = rows_ref[0, 0] - tile * row_tile  # (BLOCK,), in [0, row_tile)
     sel = (
         jax.lax.broadcasted_iota(jnp.int32, (row_tile, block), 0)
         == local[None, :]
     )
     # MXU: collisions inside the block are summed by the matmul itself.
+    # HIGHEST: the one-hot operand is exact in bfloat16 but the products
+    # are not, and the MTTKRP is float32 end to end
     out_ref[...] += jax.lax.dot(
-        sel.astype(jnp.float32), prod, preferred_element_type=jnp.float32
+        sel.astype(jnp.float32), prod, precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32
     )
 
 
 def mttkrp_pallas_call(
-    rows: Array,        # (nblocks, BLOCK) int32, tile-aligned sorted rows
-    vals: Array,        # (nblocks, BLOCK)
+    rows: Array,        # (nblocks, 1, BLOCK) int32, tile-aligned sorted rows
+    vals: Array,        # (nblocks, 1, BLOCK)
     brows: Array,       # (nblocks, BLOCK, RP) gathered factor rows
     crows: Array,       # (nblocks, BLOCK, RP) gathered (and pre-multiplied
                         #  for order > 3) remaining factor rows
@@ -85,9 +84,9 @@ def mttkrp_pallas_call(
     *,
     num_row_tiles: int,
     row_tile: int,
-    interpret: bool = True,  # CPU container: interpret by default
+    interpret: bool,
 ) -> Array:
-    nblocks, block = rows.shape
+    nblocks, _, block = rows.shape
     rp = brows.shape[-1]
     if rp % LANE:
         raise ValueError(f"rank must be padded to {LANE}, got {rp}")
@@ -96,8 +95,10 @@ def mttkrp_pallas_call(
         num_scalar_prefetch=1,
         grid=(nblocks,),
         in_specs=[
-            pl.BlockSpec((1, block), lambda b, tm: (b, 0)),
-            pl.BlockSpec((1, block), lambda b, tm: (b, 0)),
+            # (1, 1, block): the last two block dims equal the array's
+            # (1, block), which the TPU lowering requires of a 1-row block
+            pl.BlockSpec((1, 1, block), lambda b, tm: (b, 0, 0)),
+            pl.BlockSpec((1, 1, block), lambda b, tm: (b, 0, 0)),
             pl.BlockSpec((1, block, rp), lambda b, tm: (b, 0, 0)),
             pl.BlockSpec((1, block, rp), lambda b, tm: (b, 0, 0)),
         ],
@@ -107,7 +108,7 @@ def mttkrp_pallas_call(
         functools.partial(_kernel, row_tile=row_tile, block=block),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((num_row_tiles * row_tile, rp), jnp.float32),
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),  # sequential: accumulation
         ),
         interpret=interpret,

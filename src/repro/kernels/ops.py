@@ -3,9 +3,10 @@
 Each wrapper handles the shape plumbing the kernel requires (rank padding to
 the 128-lane width, block reshapes, gathers of factor rows) and slices the
 result back to logical shapes.  ``interpret`` defaults to *backend detection*
-(:func:`default_interpret`): on a real TPU the kernels compile, anywhere else
-(CPU containers, GPU hosts) they run in interpret mode — overridable per
-call for e.g. debugging compiled lowering from a CPU host.
+(:func:`default_interpret`): on a TPU the kernels compile, on the CPU backend
+(tests, rehearsals) they run in interpret mode, and any other backend is an
+error.  Pass ``interpret=False`` to compile for a described TPU from a CPU
+host (``tests/test_tpu_compile.py``).
 """
 from __future__ import annotations
 
@@ -26,8 +27,17 @@ Array = jax.Array
 
 
 def default_interpret() -> bool:
-    """True unless running on a TPU backend (where the kernels compile)."""
-    return jax.default_backend() != "tpu"
+    """False on a TPU (the kernels compile), True on the CPU backend (the
+    Pallas interpreter).  Any other backend raises: the kernels have no
+    compiled path there, and interpreting them would hide the device."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels compile only for a TPU and are interpreted only on "
+        f"the CPU backend; the default backend is {backend!r}")
 
 
 def _pad_lanes(a: Array) -> Array:
@@ -37,6 +47,15 @@ def _pad_lanes(a: Array) -> Array:
         return a
     pad = [(0, 0)] * (a.ndim - 1) + [(0, rp - r)]
     return jnp.pad(a, pad)
+
+
+def _gather_padded(factor: Array, ids: Array) -> Array:
+    """Rows ``factor[ids]`` padded to the lane width, gathered from the
+    padded factor.  XLA would otherwise move the pad after the gather, and
+    on a TPU an (nnz, R) array is laid out in 128-lane tiles anyway: the
+    gather and its padded copy would be two nnz x 128 buffers instead of
+    one (at yelp's scale, 4.2 GB each)."""
+    return jax.lax.optimization_barrier(_pad_lanes(factor))[ids]
 
 
 @partial(jax.jit, static_argnames=("interpret",))
@@ -53,16 +72,16 @@ def mttkrp(csf: CSF, factors: Sequence[Array], *,
         interpret = default_interpret()
     rank = factors[0].shape[1]
     om = csf.other_modes
-    brows = _pad_lanes(factors[om[0]][csf.other_ids[:, 0]])
-    crows = _pad_lanes(factors[om[1]][csf.other_ids[:, 1]])
+    brows = _gather_padded(factors[om[0]], csf.other_ids[:, 0])
+    crows = _gather_padded(factors[om[1]], csf.other_ids[:, 1])
     for i in range(2, len(om)):
-        crows = crows * _pad_lanes(factors[om[i]][csf.other_ids[:, i]])
+        crows = crows * _gather_padded(factors[om[i]], csf.other_ids[:, i])
 
     nblocks, block = csf.num_blocks, csf.block
     rp = brows.shape[-1]
     out = mttkrp_pallas_call(
-        csf.row_ids.reshape(nblocks, block),
-        csf.vals.reshape(nblocks, block),
+        csf.row_ids.reshape(nblocks, 1, block),
+        csf.vals.reshape(nblocks, 1, block),
         brows.reshape(nblocks, block, rp),
         crows.reshape(nblocks, block, rp),
         csf.block_tile,
@@ -98,8 +117,8 @@ def ttmc(csf: CSF, factors: Sequence[Array], *,
     nblocks, block = csf.num_blocks, csf.block
     rp = kron.shape[-1]
     out = mttkrp_pallas_call(
-        csf.row_ids.reshape(nblocks, block),
-        csf.vals.reshape(nblocks, block),
+        csf.row_ids.reshape(nblocks, 1, block),
+        csf.vals.reshape(nblocks, 1, block),
         kron.reshape(nblocks, block, rp),
         jnp.ones((nblocks, block, rp), dtype=kron.dtype),
         csf.block_tile,
@@ -131,17 +150,17 @@ def mttkrp_lin(lin: Linearized, factors: Sequence[Array], mode: int, *,
         return mttkrp_linearized(lin, factors, mode)
     rank = factors[0].shape[1]
     om = [m for m in range(lin.order) if m != mode]
-    brows = _pad_lanes(factors[om[0]][lin.decode(om[0])])
-    crows = _pad_lanes(factors[om[1]][lin.decode(om[1])])
+    brows = _gather_padded(factors[om[0]], lin.decode(om[0]))
+    crows = _gather_padded(factors[om[1]], lin.decode(om[1]))
     for m in om[2:]:
-        crows = crows * _pad_lanes(factors[m][lin.decode(m)])
+        crows = crows * _gather_padded(factors[m], lin.decode(m))
 
     nblocks, block = lin.num_blocks, lin.block
     rp = brows.shape[-1]
     out = mttkrp_lin_pallas_call(
-        lin.hi.reshape(nblocks, block),
-        lin.lo.reshape(nblocks, block),
-        lin.vals.reshape(nblocks, block),
+        lin.hi.reshape(nblocks, 1, block),
+        lin.lo.reshape(nblocks, 1, block),
+        lin.vals.reshape(nblocks, 1, block),
         brows.reshape(nblocks, block, rp),
         crows.reshape(nblocks, block, rp),
         lin.block_tile,
@@ -177,9 +196,9 @@ def ttmc_lin(lin: Linearized, factors: Sequence[Array], mode: int, *,
     nblocks, block = lin.num_blocks, lin.block
     rp = kron.shape[-1]
     out = mttkrp_lin_pallas_call(
-        lin.hi.reshape(nblocks, block),
-        lin.lo.reshape(nblocks, block),
-        lin.vals.reshape(nblocks, block),
+        lin.hi.reshape(nblocks, 1, block),
+        lin.lo.reshape(nblocks, 1, block),
+        lin.vals.reshape(nblocks, 1, block),
         kron.reshape(nblocks, block, rp),
         jnp.ones((nblocks, block, rp), dtype=kron.dtype),
         lin.block_tile,

@@ -14,10 +14,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 spells CompilerParams "TPUCompilerParams"
-_compiler_params = getattr(pltpu, "CompilerParams",
-                           getattr(pltpu, "TPUCompilerParams", None))
-
 Array = jax.Array
 
 
@@ -34,7 +30,7 @@ def _kernel(a_ref, out_ref):
     )
 
 
-def syrk_pallas_call(a: Array, *, blk: int = 512, interpret: bool = True) -> Array:
+def syrk_pallas_call(a: Array, *, blk: int = 512, interpret: bool) -> Array:
     rows, rp = a.shape
     if rows % blk:
         raise ValueError(f"rows ({rows}) must be padded to blk ({blk})")
@@ -45,7 +41,7 @@ def syrk_pallas_call(a: Array, *, blk: int = 512, interpret: bool = True) -> Arr
         in_specs=[pl.BlockSpec((blk, rp), lambda k: (k, 0))],
         out_specs=pl.BlockSpec((rp, rp), lambda k: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((rp, rp), jnp.float32),
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,

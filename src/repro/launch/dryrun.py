@@ -1,10 +1,13 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell with
 512 placeholder host devices, record memory/cost/collective analysis and the
-three-term roofline.  MUST set XLA_FLAGS before any other import (jax locks
-the device count on first init) — hence the two lines above.
+three-term roofline of :data:`MODELED_KIND`.  MUST set XLA_FLAGS before any
+other import (jax locks the device count on first init) — hence the lines
+above.  It is a CPU simulation by design: JAX_PLATFORMS=cpu keeps it (and
+the per-cell children of ``--all``) off a TPU the host may hold.
 
   PYTHONPATH=src python -m repro.launch.dryrun --arch gemma-7b --shape train_4k
   PYTHONPATH=src python -m repro.launch.dryrun --all          # full matrix
@@ -31,6 +34,10 @@ from repro.optim import OPTIMIZERS
 from repro.utils import roofline as RL
 
 ARTIFACTS = Path(__file__).resolve().parents[3] / "artifacts" / "dryrun"
+
+# the chip whose published peaks bound the roofline (utils.roofline.PEAKS):
+# the placeholder devices are host CPUs, so the kind is named, not detected
+MODELED_KIND = "TPU v5 lite"
 
 # per-arch optimizer (Adafactor where AdamW state cannot fit the mesh)
 ARCH_OPT = {"kimi-k2-1t-a32b": "adafactor"}
@@ -179,10 +186,10 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
         flops=probe["flops"], bytes_accessed=probe["bytes"],
         wire_bytes=probe["wire"], collectives=probe["collectives"],
         n_chips=meta["n_chips"],
-        model_flops=RL.model_flops_estimate(cfg, shape))
+        model_flops=RL.model_flops_estimate(cfg, shape), kind=MODELED_KIND)
 
     art = {
-        "cell": cell_id, **meta,
+        "cell": cell_id, **meta, "device_kind": MODELED_KIND,
         "lower_s": round(t_lower, 2), "compile_s": round(t_compile, 2),
         "memory": {
             "argument_bytes": mem.argument_size_in_bytes,
@@ -200,7 +207,8 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
     _write(out_dir, cell_id, art)
     print(f"[dryrun] {cell_id}: ok  compile={t_compile:.1f}s  "
           f"dominant={rl.dominant}  bound={rl.bound_s*1e3:.2f}ms  "
-          f"peak={art['memory']['peak_estimate_gib']}GiB")
+          f"peak={art['memory']['peak_estimate_gib']}GiB  "
+          f"kind={MODELED_KIND!r}")
     return art
 
 
@@ -222,7 +230,7 @@ def _probe_costs(arch: str, shape_name: str, *, multi_pod: bool,
         lowered, _ = build_cell(arch, shape_name, multi_pod=multi_pod,
                                 overrides=ov)
         comp = lowered.compile()
-        cost = RL.normalize_cost(comp.cost_analysis())
+        cost = comp.cost_analysis()
         colls = RL.parse_collectives(comp.as_text())
         results.append({
             "flops": float(cost.get("flops", 0.0)),
@@ -323,12 +331,13 @@ def run_cpals(workload: str, *, multi_pod: bool, out_dir: Path = ARTIFACTS,
     mem = compiled.memory_analysis()
     hlo = compiled.as_text()
     rl = RL.analyze(cost, hlo, n_chips=mesh.devices.size,
-                    model_flops=info["model_flops"])
+                    model_flops=info["model_flops"], kind=MODELED_KIND)
     cell_id = f"{workload}__iteration__{'multi' if multi_pod else 'single'}"
     if tag:
         cell_id += f"__{tag}"
     art = {
         "cell": cell_id, "arch": workload, "shape": "iteration",
+        "device_kind": MODELED_KIND,
         "mesh": dict(mesh.shape), "n_chips": mesh.devices.size,
         "lower_s": round(t_lower, 2), "compile_s": round(t_compile, 2),
         "memory": {
@@ -345,7 +354,8 @@ def run_cpals(workload: str, *, multi_pod: bool, out_dir: Path = ARTIFACTS,
     }
     _write(out_dir, cell_id, art)
     print(f"[dryrun] {cell_id}: ok  compile={t_compile:.1f}s  "
-          f"dominant={rl.dominant}  bound={rl.bound_s*1e3:.2f}ms")
+          f"dominant={rl.dominant}  bound={rl.bound_s*1e3:.2f}ms  "
+          f"kind={MODELED_KIND!r}")
     return art
 
 
@@ -385,7 +395,8 @@ def run_all(out_dir: Path, *, resume: bool = True, jobs: int = 1) -> None:
             procs = _reap(procs)
             time.sleep(0.5)
         p = subprocess.Popen(args, stdout=subprocess.PIPE,
-                             stderr=subprocess.STDOUT, text=True)
+                             stderr=subprocess.STDOUT, text=True,
+                             env={**os.environ, "JAX_PLATFORMS": "cpu"})
         procs.append((p, f"{arch}/{shape}/{mp}"))
     while procs:
         procs = _reap(procs)

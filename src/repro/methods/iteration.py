@@ -43,13 +43,14 @@ class IterationRecorder:
 
     ``iteration(it)`` is the context manager wrapping one iteration's
     work; ``progress(it, fit, fit_prev)`` computes the dtype-consistent
-    delta, prints the shared verbose line, and returns the delta for the
-    driver's tol check.  With observability disabled (no active tracer)
-    the per-iteration cost is one perf_counter pair and an ``is None``
-    check — no tracer or registry traffic at all.
+    delta, records the iteration's wall time, prints the shared verbose
+    line, and returns the delta for the fit loop's tol check.  With
+    observability disabled (no active tracer) the per-iteration cost is one
+    perf_counter pair and an ``is None`` check — no tracer or registry
+    traffic at all.
     """
 
-    __slots__ = ("method", "monitor", "verbose", "_observed")
+    __slots__ = ("method", "monitor", "verbose", "_observed", "_t0")
 
     def __init__(self, method: str, *, monitor=None,
                  verbose: bool = False) -> None:
@@ -60,10 +61,23 @@ class IterationRecorder:
 
     @contextmanager
     def iteration(self, it: int) -> Iterator[None]:
-        t0 = time.perf_counter()
+        self._t0 = time.perf_counter()
         with obs_trace.span("iteration", method=self.method, i=int(it)):
             yield
-        dt = time.perf_counter() - t0
+
+    def progress(self, it: int, fit, fit_prev) -> float:
+        """One dtype-consistent delta scalar: cast both fits to python
+        float FIRST, then subtract — printing ``float(fit - fit_prev)``
+        (a bf16/f32 device subtraction) while comparing
+        ``abs(float(fit) - float(fit_prev))`` against tol let the
+        printed delta disagree with the stop decision.
+
+        Reading the fit to the host waits for the iteration's device work,
+        so the wall time recorded here runs from :meth:`iteration`'s entry
+        to that read: the iteration's time on the device, not only its
+        dispatch."""
+        delta = float(fit) - float(fit_prev)
+        dt = time.perf_counter() - self._t0
         record_iteration(self.monitor, dt)
         if self.monitor is not None:
             # escalations land in the metrics registry inside check() —
@@ -75,14 +89,6 @@ class IterationRecorder:
             registry.histogram("fit.iteration_ms").observe(dt * 1e3)
             record_event("iteration", method=self.method, i=int(it),
                          ms=dt * 1e3)
-
-    def progress(self, it: int, fit, fit_prev) -> float:
-        """One dtype-consistent delta scalar: cast both fits to python
-        float FIRST, then subtract — printing ``float(fit - fit_prev)``
-        (a bf16/f32 device subtraction) while comparing
-        ``abs(float(fit) - float(fit_prev))`` against tol let the
-        printed delta disagree with the stop decision."""
-        delta = float(fit) - float(fit_prev)
         if self.verbose:
             print(f"  its = {it + 1}  fit = {float(fit):.6f}  "
                   f"delta = {delta:+.3e}")

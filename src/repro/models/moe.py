@@ -14,7 +14,6 @@ import jax
 import numpy as np
 import jax.numpy as jnp
 
-from repro.dist.collectives import shard_map
 
 from .config import ModelConfig
 from .params import ParamSpec
@@ -248,7 +247,7 @@ def moe_ffn_ep(p: dict, cfg: ModelConfig, x: Array, mesh) -> tuple[Array, dict]:
         drop = jax.lax.pmean(jax.lax.pmean(drop, "model"), dp_axes)
         return out.reshape(bl, sl, d), drop
 
-    smapped = shard_map(
+    smapped = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(dp_axes, "model", None), P(), wspec_g, wspec_g, wspec_d),
         out_specs=(P(dp_axes, "model", None), P()),

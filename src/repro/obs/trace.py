@@ -165,11 +165,8 @@ class Tracer:
         self._pid = os.getpid()
         self._annotation_cls = None
         if self.enabled and xla_annotations:
-            try:
-                from jax.profiler import TraceAnnotation
-                self._annotation_cls = TraceAnnotation
-            except Exception:  # jax absent or too old — spans still work
-                self._annotation_cls = None
+            from jax.profiler import TraceAnnotation
+            self._annotation_cls = TraceAnnotation
 
     # -- span construction -------------------------------------------------
 

@@ -107,7 +107,10 @@ def make_score_fn(decomp, *, user_mode: int = 0,
             weights = weights * jnp.sum(decomp.factors[m], axis=0)
 
         def score(users: Array) -> Array:
-            return (user_f[users] * weights[None, :]) @ item_f.T
+            # f32 scores: a TPU's default precision would rank by
+            # bfloat16-rounded products
+            return jnp.matmul(user_f[users] * weights[None, :], item_f.T,
+                              precision=jax.lax.Precision.HIGHEST)
 
         return score
 

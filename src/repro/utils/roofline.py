@@ -1,4 +1,4 @@
-"""Three-term roofline from a compiled dry-run artifact (TPU v5e targets).
+"""Three-term roofline from a compiled dry-run artifact.
 
   compute term    = HLO_FLOPs_per_device / peak_FLOP/s
   memory term     = HLO_bytes_per_device / HBM_bw
@@ -18,8 +18,8 @@ convert to ring-algorithm wire bytes:
   all-to-all       B * (g-1)/g
   collective-perm  B
 
-Hardware constants (TPU v5e): 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link
-ICI.
+The peaks come from :data:`PEAKS`, keyed by the ``device_kind`` jax
+reports; a kind that is not in the table is an error, never a default.
 """
 from __future__ import annotations
 
@@ -30,9 +30,37 @@ from typing import Any
 
 import numpy as np
 
-PEAK_FLOPS = 197e12     # bf16 per chip
-HBM_BW = 819e9          # bytes/s per chip
-ICI_BW = 50e9           # bytes/s per link
+
+@dataclasses.dataclass(frozen=True)
+class Peaks:
+    """Published per-chip peaks."""
+    flops: float      # bf16 FLOP/s
+    hbm_bw: float     # HBM bytes/s
+    hbm_bytes: float  # HBM capacity
+    ici_bw: float     # bytes/s per ICI link
+    source: str
+
+
+# keyed by ``jax.Device.device_kind``
+PEAKS: dict[str, Peaks] = {
+    "TPU v5 lite": Peaks(
+        flops=197e12, hbm_bw=819e9, hbm_bytes=16e9,
+        # 1,600 Gbit/s of interconnect per chip over its four ICI links
+        ici_bw=50e9,
+        source="Google Cloud documentation, 'TPU v5e'"),
+}
+
+
+def peaks_for(kind: str) -> Peaks:
+    """The peaks of ``kind`` (a ``device_kind`` string); raises for a kind
+    the table does not hold."""
+    try:
+        return PEAKS[kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {kind!r}; known: "
+            f"{tuple(PEAKS)}") from None
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -131,11 +159,12 @@ class Roofline:
 
 
 def analyze_values(*, flops: float, bytes_accessed: float, wire_bytes: float,
-                   collectives: dict, n_chips: int,
-                   model_flops: float) -> Roofline:
-    compute_s = flops / PEAK_FLOPS
-    memory_s = bytes_accessed / HBM_BW
-    collective_s = wire_bytes / ICI_BW
+                   collectives: dict, n_chips: int, model_flops: float,
+                   kind: str) -> Roofline:
+    peaks = peaks_for(kind)
+    compute_s = flops / peaks.flops
+    memory_s = bytes_accessed / peaks.hbm_bw
+    collective_s = wire_bytes / peaks.ici_bw
     terms = {"compute": compute_s, "memory": memory_s,
              "collective": collective_s}
     dominant = max(terms, key=terms.get)
@@ -148,50 +177,18 @@ def analyze_values(*, flops: float, bytes_accessed: float, wire_bytes: float,
     )
 
 
-def normalize_cost(cost) -> dict:
-    """XLA cost analysis as a plain dict.  Newer jax returns the dict
-    directly; 0.4.x returns a one-element list of dicts."""
-    if isinstance(cost, (list, tuple)):
-        return dict(cost[0]) if cost else {}
-    return dict(cost)
-
-
-class CompatCompiled:
-    """Wraps a jax Compiled so ``cost_analysis()`` is a dict on every
-    jax version; everything else delegates."""
-
-    def __init__(self, compiled):
-        self._compiled = compiled
-
-    def cost_analysis(self) -> dict:
-        return normalize_cost(self._compiled.cost_analysis())
-
-    def __getattr__(self, name):
-        return getattr(self._compiled, name)
-
-
-class CompatLowered:
-    """Wraps a jax Lowered so ``compile()`` yields a CompatCompiled."""
-
-    def __init__(self, lowered):
-        self._lowered = lowered
-
-    def compile(self, *args, **kwargs) -> CompatCompiled:
-        return CompatCompiled(self._lowered.compile(*args, **kwargs))
-
-    def __getattr__(self, name):
-        return getattr(self._lowered, name)
-
-
-def analyze(cost, hlo: str, *, n_chips: int, model_flops: float) -> Roofline:
+def analyze(cost: dict, hlo: str, *, n_chips: int, model_flops: float,
+            kind: str) -> Roofline:
+    """Roofline of one compiled program: ``cost`` is its
+    ``cost_analysis()`` dict, ``hlo`` its optimized text, ``kind`` the
+    ``device_kind`` whose peaks bound it."""
     colls = parse_collectives(hlo)
-    cost = normalize_cost(cost)
     return analyze_values(
         flops=float(cost.get("flops", 0.0)),
         bytes_accessed=float(cost.get("bytes accessed", 0.0)),
         wire_bytes=sum(c["wire"] for c in colls),
         collectives=collective_summary(colls),
-        n_chips=n_chips, model_flops=model_flops)
+        n_chips=n_chips, model_flops=model_flops, kind=kind)
 
 
 def model_flops_estimate(cfg, shape) -> float:

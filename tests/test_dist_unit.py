@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 
 from repro.dist import (StragglerMonitor, axis_product, batch_axes,
-                        cpals_axes)
+                        cpals_axes, make_mesh)
 from repro.dist.compress import (compress_grads_int8, compression_ratio,
                                  decompress_grads_int8, init_error_feedback)
 
@@ -198,12 +198,14 @@ def test_compression_ratio_counts_wire_bytes():
 # ---------------------------------------------------------------------------
 
 def test_cpals_axes_single_and_multipod():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     ax = cpals_axes(mesh)
     assert ax.row == ("data",) and ax.col == "model"
     assert ax.n_row == 1 and ax.n_col == 1 and ax.n_all == 1
     assert ax.all_axes == ("data", "model")
-    assert tuple(ax.grid_spec()) == (("data",), "model")
+    # jax 0.9 normalizes a one-axis tuple entry to the bare axis name, so
+    # compare specs, not their tuples
+    assert ax.grid_spec() == jax.sharding.PartitionSpec(("data",), "model")
     assert axis_product(mesh, ("data", "model")) == 1
     assert axis_product(mesh, ()) == 1
 
@@ -214,6 +216,6 @@ def test_batch_axes_pod_rule():
 
 
 def test_cpals_axes_requires_model_axis():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     with pytest.raises(ValueError):
         cpals_axes(mesh)

@@ -28,10 +28,11 @@ def test_dist_cpals_matches_single_device():
     on a 4x2 mesh of host devices."""
     out = run_py("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.dist.collectives import make_mesh
         from repro.core import random_sparse, cp_als
         from repro.core.cpals import init_factors
         from repro.core.distributed import dist_cp_als
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         key = jax.random.PRNGKey(5)
         t = random_sparse((37, 23, 19), 1500, key)
 
@@ -62,9 +63,10 @@ def test_dist_cpals_multipod_mesh():
     """The pod axis joins the row partition: (pod=2, data=2, model=2)."""
     out = run_py("""
         import jax, jax.numpy as jnp
+        from repro.dist.collectives import make_mesh
         from repro.core import random_sparse
         from repro.core.distributed import dist_cp_als
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         t = random_sparse((29, 17, 13), 900, jax.random.PRNGKey(1))
         factors, lam, fit = dist_cp_als(t, 4, mesh, niters=4)
         assert all(bool(jnp.all(jnp.isfinite(f))) for f in factors)
@@ -80,6 +82,7 @@ def test_dryrun_mini_cell_and_roofline_parser():
     parser must find the data-parallel gradient all-reduce."""
     out = run_py("""
         import jax, jax.numpy as jnp, dataclasses
+        from repro.dist.collectives import make_mesh
         from repro import configs
         from repro.launch.mesh import rules_for, sharding_fn, batch_sharding
         from repro.launch.steps import make_train_step
@@ -90,7 +93,7 @@ def test_dryrun_mini_cell_and_roofline_parser():
         from repro.utils import roofline as RL
         from repro.launch.dryrun import _map_axes, _sds
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         cfg = configs.smoke_of(configs.get("llama3.2-3b"))
         cfg = dataclasses.replace(cfg, vocab=1024, d_model=128, d_ff=256,
                                   num_heads=8, num_kv_heads=2)
@@ -113,7 +116,8 @@ def test_dryrun_mini_cell_and_roofline_parser():
         compiled = lowered.compile()
         cost = compiled.cost_analysis()
         hlo = compiled.as_text()
-        rl = RL.analyze(cost, hlo, n_chips=8, model_flops=6.0 * 1e6 * 1024)
+        rl = RL.analyze(cost, hlo, n_chips=8, model_flops=6.0 * 1e6 * 1024,
+                        kind="TPU v5 lite")
         print("flops", rl.flops, "colls", sorted(rl.collectives))
         assert rl.flops > 0 and rl.bytes_accessed > 0
         assert "all-reduce" in rl.collectives, rl.collectives
@@ -130,8 +134,9 @@ def test_dist_cpals_dryrun_lowering():
     (same code path the production dry-run uses for cpals-* cells)."""
     out = run_py("""
         import jax
+        from repro.dist.collectives import make_mesh
         from repro.core.distributed import build_dist_cpals_lowered
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         lowered, info = build_dist_cpals_lowered("cpals-yelp", mesh)
         compiled = lowered.compile()
         cost = compiled.cost_analysis()
@@ -177,10 +182,11 @@ def test_dist_cpals_shard_c_and_mode_order_equivalent():
     numerically equivalent to the baseline distributed algorithm."""
     out = run_py("""
         import jax, jax.numpy as jnp
+        from repro.dist.collectives import make_mesh
         from repro.core import random_sparse
         from repro.core.cpals import init_factors
         from repro.core.distributed import dist_cp_als
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         t = random_sparse((37, 23, 19), 1500, jax.random.PRNGKey(5))
         init = init_factors(t.dims, 5, jax.random.PRNGKey(0))
         f1, l1, fit1 = dist_cp_als(t, 5, mesh, niters=5, init=init)
@@ -206,11 +212,12 @@ def test_dist_cpals_plan_interface():
     equivalent to the fixed scatter path."""
     out = run_py("""
         import jax, jax.numpy as jnp
+        from repro.dist.collectives import make_mesh
         from repro.core import random_sparse
         from repro.core.cpals import init_factors
         from repro.core.distributed import dist_cp_als
         from repro.plan import plan_decomposition
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         t = random_sparse((37, 23, 19), 1500, jax.random.PRNGKey(5))
         init = init_factors(t.dims, 5, jax.random.PRNGKey(0))
         plan = plan_decomposition(t, "auto", rank=5,
@@ -234,10 +241,11 @@ def test_ep_moe_matches_dense_dispatch():
     """Expert-parallel shard_map MoE == dense-dispatch oracle (fwd + grads)."""
     out = run_py("""
         import jax, jax.numpy as jnp
+        from repro.dist.collectives import make_mesh
         from repro.models.config import ModelConfig, MoEConfig
         from repro.models.moe import moe_ffn_ep, _moe_ffn_dense_dispatch, moe_specs
         from repro.models.params import init_params
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = ModelConfig(name="m", family="moe", pattern=("moe",),
                           num_layers=1, d_model=32, num_heads=2,
                           num_kv_heads=2, head_dim=16, d_ff=64, vocab=64,

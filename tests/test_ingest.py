@@ -10,6 +10,7 @@ import pytest
 
 from repro.core import SparseTensor, cp_als, init_factors, random_sparse
 from repro.core.cpals import CPALSState
+from repro.dist.collectives import make_mesh
 from repro.ingest import (IngestCache, Ingested, Relabeling, compact,
                           content_key, convert_tns, degree_sort, ingest,
                           random_block, read_tns, read_tnsb, write_tns,
@@ -421,7 +422,7 @@ def test_dist_cpals_accepts_ingested():
     from repro.core.distributed import dist_cp_als
 
     t = skewed_tensor(nnz=400)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     f_nat, lam_nat, fit_nat = dist_cp_als(t, 3, mesh, niters=2, key=KEY)
     ing = ingest(t, reorder="degree_sort")
     f_re, lam_re, fit_re = dist_cp_als(ing, 3, mesh, niters=2, key=KEY)

@@ -10,6 +10,7 @@ from repro.core import (SparseTensor, available_impls, build_csf,
                         build_workspace, cp_als, get_impl, init_factors,
                         mttkrp, random_sparse)
 from repro.core.csf import CSF, build_csf_loop_reference
+from repro.dist.collectives import make_mesh
 from repro.plan import (CONTENTION_THRESHOLD, DecompPlan, mode_stats,
                         plan_decomposition)
 from repro.utils.report import plan_report
@@ -220,16 +221,20 @@ def test_dist_rejects_unsupported_impl():
     rather than silently substituting scatter-add."""
     from repro.core.distributed import dist_cp_als
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     with pytest.raises(ValueError, match="shard_map body"):
         dist_cp_als(skewed_tensor(nnz=50), 3, mesh, impl="pallas")
 
 
-def test_default_interpret_matches_backend():
+def test_default_interpret_matches_backend(monkeypatch):
     from repro.kernels import ops
 
     want = jax.default_backend() != "tpu"
     assert ops.default_interpret() is want
+    # no silent interpretation on a backend the kernels do not target
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="'gpu'"):
+        ops.default_interpret()
 
 
 def test_cpals_step_builder_executes_plan():

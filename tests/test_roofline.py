@@ -59,11 +59,23 @@ def test_allreduce_wire_model():
 def test_analyze_dominant_term():
     r = RL.analyze_values(flops=197e12, bytes_accessed=819e9 * 2,
                           wire_bytes=0, collectives={}, n_chips=4,
-                          model_flops=197e12 * 2)
+                          model_flops=197e12 * 2, kind="TPU v5 lite")
     assert r.dominant == "memory"
     assert r.compute_s == pytest.approx(1.0)
     assert r.memory_s == pytest.approx(2.0)
     assert r.useful_ratio == pytest.approx(0.5)
+
+
+def test_peaks_are_keyed_by_device_kind():
+    v5e = RL.peaks_for("TPU v5 lite")
+    assert (v5e.flops, v5e.hbm_bw, v5e.hbm_bytes) == (197e12, 819e9, 16e9)
+    assert "TPU v5e" in v5e.source
+    # a kind without published peaks is an error, never a default
+    with pytest.raises(ValueError, match="no published peaks"):
+        RL.peaks_for("cpu")
+    with pytest.raises(ValueError, match="no published peaks"):
+        RL.analyze({"flops": 1.0}, "", n_chips=1, model_flops=1.0,
+                   kind="TPU v4")
 
 
 def test_model_flops_estimate_kinds():

@@ -1,0 +1,117 @@
+"""Compile the Pallas kernels for a described TPU v5e at yelp's geometry.
+
+Nothing runs: the TPU compiler, installed with jax, compiles for a chip that
+is described and not attached, and refuses what the chip would refuse (block
+shapes the Mosaic lowering cannot tile, programs larger than HBM).  Interpret
+mode accepts all of that, so these tests are what keeps the kernels
+compilable between chip runs.
+
+yelp at its published scale (``PAPER_DATASETS``: 8.0M non-zeros) sorts into
+at most 15,902 blocks of 512 per mode; the paper's rank 35 pads to 128 lanes.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core.csf import CSF
+from repro.kernels import ops
+from repro.kernels.linearized_pallas import mttkrp_lin_pallas_call
+from repro.kernels.mttkrp_pallas import LANE, mttkrp_pallas_call
+from repro.utils.roofline import peaks_for
+
+DIMS = (41_000, 11_000, 75_000)
+NNZ = 7_998_641
+BLOCKS = (15_779, 15_666, 15_902)  # per mode, build_csf at seed 0 on the CPU
+BLOCK, ROW_TILE, RANK = 512, 128, 35
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler can be loaded here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.experimental.compilation_cache import compilation_cache
+
+    # a compile for a described chip is written to a persistent cache but
+    # cannot be read back without the chip: keep these out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _sds(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _csf(sharding, mode: int) -> CSF:
+    pnnz = BLOCKS[mode] * BLOCK
+    return CSF(mode=mode,
+               row_ids=_sds(sharding, (pnnz,), jnp.int32),
+               other_ids=_sds(sharding, (pnnz, 2), jnp.int32),
+               vals=_sds(sharding, (pnnz,), jnp.float32),
+               block_tile=_sds(sharding, (BLOCKS[mode],), jnp.int32),
+               dims=DIMS, nnz=NNZ, block=BLOCK, row_tile=ROW_TILE)
+
+
+def _kernel_operands(sharding, width: int, index_dtype=jnp.int32):
+    nb = max(BLOCKS)
+    return (_sds(sharding, (nb, 1, BLOCK), index_dtype),
+            _sds(sharding, (nb, BLOCK, width), jnp.float32),
+            _sds(sharding, (nb,), jnp.int32))
+
+
+def test_mttkrp_kernel_compiles(one_chip):
+    rows, rows_f, tiles = _kernel_operands(one_chip, LANE)
+    vals = _sds(one_chip, rows.shape, jnp.float32)
+    call = jax.jit(lambda *a: mttkrp_pallas_call(
+        *a, num_row_tiles=-(-DIMS[2] // ROW_TILE), row_tile=ROW_TILE,
+        interpret=False))
+    hlo = call.lower(rows, vals, rows_f, rows_f, tiles).compile().as_text()
+    assert "tpu_custom_call" in hlo
+
+
+def test_linearized_kernel_compiles(one_chip):
+    hi, rows_f, tiles = _kernel_operands(one_chip, LANE, jnp.uint32)
+    vals = _sds(one_chip, hi.shape, jnp.float32)
+    call = jax.jit(lambda *a: mttkrp_lin_pallas_call(
+        *a, num_row_tiles=-(-DIMS[2] // ROW_TILE), row_tile=ROW_TILE,
+        offset=0, width=17, interpret=False))
+    hlo = call.lower(hi, hi, vals, rows_f, rows_f,
+                     tiles).compile().as_text()
+    assert "tpu_custom_call" in hlo
+
+
+def test_ttmc_compiles_at_kronecker_width(one_chip):
+    # Tucker ranks (8, 8, 8): each mode's Kronecker width is 8 x 8 = 64
+    factors = tuple(_sds(one_chip, (d, 8), jnp.float32) for d in DIMS)
+    compiled = jax.jit(ops.ttmc, static_argnames=("interpret",)).lower(
+        _csf(one_chip, 0), factors, interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("mode", [0, 1, 2])
+def test_mttkrp_fits_one_chip(one_chip, topo, mode):
+    """The whole jitted per-mode MTTKRP at rank 35: the gathers, the kernel
+    and the slicing, within one chip's HBM."""
+    factors = tuple(_sds(one_chip, (d, RANK), jnp.float32) for d in DIMS)
+    compiled = jax.jit(ops.mttkrp, static_argnames=("interpret",)).lower(
+        _csf(one_chip, mode), factors, interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    hbm = peaks_for(topo.devices[0].device_kind).hbm_bytes
+    # two lane-padded gathered operands of nnz x 128 f32 dominate: 8.3 GB
+    assert total < 0.6 * hbm, (total, hbm)
