@@ -204,22 +204,27 @@ def _mode_epilogue(m_mat, factors, grams, norm_x_sq, *, mode: int,
     the device and XLA fuses the small matrix ops end-to-end.  Returns the
     *full* updated ``(factors, grams, lam, fit)`` tuples so the factor
     buffers can be donated across calls (see :func:`fused_mode_epilogue`)."""
-    v = hadamard_grams(grams, mode)
-    # solve_gram, not solve_cholesky: inside the fused trace the GEMM
-    # formulation is what makes the collapsed chain beat the per-routine
-    # driver on CPU (cho_solve with I right-hand sides is scalar there)
-    a_new = solve_gram(m_mat, v)
-    a_new, lam = normalize(a_new, kind=norm_kind)
-    g_new = gram(a_new)
-    factors = tuple(a_new if m == mode else f for m, f in enumerate(factors))
-    grams = tuple(g_new if m == mode else g for m, g in enumerate(grams))
-    if with_fit:
-        fit = kruskal_fit(norm_x_sq, lam, grams, m_mat, factors[-1])
-    else:
-        # No fit was computed: return NaN, not a fake 0.0 that downstream
-        # reports would read as "converged to fit 0".  The driver keeps the
-        # last *computed* fit (previous iteration / restored state) instead.
-        fit = jnp.array(jnp.nan, dtype=factors[0].dtype)
+    with jax.named_scope(f"epilogue/mode{mode}"):
+        v = hadamard_grams(grams, mode)
+        # solve_gram, not solve_cholesky: inside the fused trace the GEMM
+        # formulation is what makes the collapsed chain beat the
+        # per-routine path on CPU (cho_solve with I right-hand sides is
+        # scalar there)
+        a_new = solve_gram(m_mat, v)
+        a_new, lam = normalize(a_new, kind=norm_kind)
+        g_new = gram(a_new)
+        factors = tuple(a_new if m == mode else f
+                        for m, f in enumerate(factors))
+        grams = tuple(g_new if m == mode else g
+                      for m, g in enumerate(grams))
+        if with_fit:
+            fit = kruskal_fit(norm_x_sq, lam, grams, m_mat, factors[-1])
+        else:
+            # No fit was computed: return NaN, not a fake 0.0 that
+            # downstream reports would read as "converged to fit 0".  The
+            # ALS loop keeps the last *computed* fit (previous iteration /
+            # restored state) instead.
+            fit = jnp.array(jnp.nan, dtype=factors[0].dtype)
     return factors, grams, lam, fit
 
 

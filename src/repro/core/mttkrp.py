@@ -158,13 +158,17 @@ def mttkrp_gather_scatter(
     if isinstance(t, CSF):
         if t.mode != mode:
             raise ValueError(f"CSF is built for mode {t.mode}, asked {mode}")
-        prod = _krp_rows_csf(t, factors)
-        out = jnp.zeros((t.dims[mode], prod.shape[1]), dtype=prod.dtype)
-        return out.at[t.row_ids].add(prod, mode="drop")
+        with jax.named_scope("gather"):
+            prod = _krp_rows_csf(t, factors)
+        with jax.named_scope("kernel"):
+            out = jnp.zeros((t.dims[mode], prod.shape[1]), dtype=prod.dtype)
+            return out.at[t.row_ids].add(prod, mode="drop")
     rank = factors[0].shape[1]
-    prod = _krp_rows(t.inds, factors, mode, t.vals)
-    out = jnp.zeros((t.dims[mode], rank), dtype=prod.dtype)
-    return out.at[t.inds[:, mode]].add(prod, mode="drop")
+    with jax.named_scope("gather"):
+        prod = _krp_rows(t.inds, factors, mode, t.vals)
+    with jax.named_scope("kernel"):
+        out = jnp.zeros((t.dims[mode], rank), dtype=prod.dtype)
+        return out.at[t.inds[:, mode]].add(prod, mode="drop")
 
 
 # ---------------------------------------------------------------------------
@@ -186,9 +190,12 @@ def mttkrp_segment(csf: CSF, factors: Sequence[Array],
         raise TypeError("segment impl needs a CSF workspace (build_csf(t, mode))")
     if mode is not None and csf.mode != mode:
         raise ValueError(f"CSF is built for mode {csf.mode}, asked {mode}")
-    prod = _krp_rows_csf(csf, factors)
-    return jax.ops.segment_sum(prod, csf.row_ids, num_segments=csf.num_rows,
-                               indices_are_sorted=True)
+    with jax.named_scope("gather"):
+        prod = _krp_rows_csf(csf, factors)
+    with jax.named_scope("kernel"):
+        return jax.ops.segment_sum(prod, csf.row_ids,
+                                   num_segments=csf.num_rows,
+                                   indices_are_sorted=True)
 
 
 def mttkrp_pallas(csf: CSF, factors: Sequence[Array],
@@ -228,16 +235,19 @@ def mttkrp_linearized(ws, factors: Sequence[Array], mode: int) -> Array:
     scatter-add (mutex/atomic regime) — ALTO's recompute path, at zero extra
     resident memory and no re-sort."""
     lin = _require_lin(ws)
-    prod = lin.vals[:, None].astype(factors[0].dtype)
-    for m in range(lin.order):
-        if m != mode:
-            prod = prod * factors[m][lin.decode(m)]
-    rows = lin.decode(mode)
-    if mode == lin.sort_mode:
-        return jax.ops.segment_sum(prod, rows, num_segments=lin.dims[mode],
-                                   indices_are_sorted=True)
-    out = jnp.zeros((lin.dims[mode], prod.shape[1]), dtype=prod.dtype)
-    return out.at[rows].add(prod, mode="drop")
+    with jax.named_scope("gather"):
+        prod = lin.vals[:, None].astype(factors[0].dtype)
+        for m in range(lin.order):
+            if m != mode:
+                prod = prod * factors[m][lin.decode(m)]
+    with jax.named_scope("kernel"):
+        rows = lin.decode(mode)
+        if mode == lin.sort_mode:
+            return jax.ops.segment_sum(prod, rows,
+                                       num_segments=lin.dims[mode],
+                                       indices_are_sorted=True)
+        out = jnp.zeros((lin.dims[mode], prod.shape[1]), dtype=prod.dtype)
+        return out.at[rows].add(prod, mode="drop")
 
 
 def mttkrp_linearized_pallas(ws, factors: Sequence[Array], mode: int) -> Array:
@@ -455,4 +465,5 @@ def mttkrp(
             "repro.plan.plan_decomposition (or call cp_als(impl='auto')) "
             "and dispatch on the per-mode plan")
     spec = get_impl(impl)
-    return spec.fn(x, factors, mode)
+    with jax.named_scope(f"mttkrp/mode{mode}"):
+        return spec.fn(x, factors, mode)

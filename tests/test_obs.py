@@ -113,18 +113,23 @@ def test_traced_decorator():
 
 def test_thread_isolation():
     tracer = Tracer(xla_annotations=False)
+    # both workers stay alive until both have recorded: the OS may reuse
+    # an exited thread's ident, and the tids must then still differ
+    both = threading.Barrier(2, timeout=10)
 
     def worker(i):
         with tracer.activate():  # threads start with a fresh context
             with tracer.span(f"root-t{i}"):
                 with tracer.span("child"):
                     pass
+        both.wait()
 
     threads = [threading.Thread(target=worker, args=(i,)) for i in range(2)]
     for t in threads:
         t.start()
     for t in threads:
-        t.join()
+        t.join(timeout=10)
+        assert not t.is_alive()
     events = tracer.events()
     assert len(events) == 4
     roots = {e["name"]: e for e in events if e["name"].startswith("root")}
@@ -177,6 +182,44 @@ def test_clear_resets_events_and_epoch():
         pass
     (e,) = tracer.events()
     assert e["ts"] < 1e6  # fresh epoch: ts restarts near zero
+
+
+def test_span_lands_on_the_xla_trace_clock(tmp_path):
+    """A span opens a profiler annotation: in an XLA trace it lies on a
+    host plane under its own name and holds the ops of the jitted call
+    made inside it, so a gap in the device's work can be put down to what
+    the program's host code was doing then."""
+    import glob
+
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+
+    @jax.jit
+    def step(x):
+        return jnp.tanh(x @ x).sum()
+
+    x = jnp.ones((256, 256))
+    step(x).block_until_ready()  # compiled before the trace
+    tracer = Tracer()
+    with jax.profiler.trace(str(tmp_path)):
+        with tracer.activate(), span("obs-bridge"):
+            step(x).block_until_ready()
+    assert [e["name"] for e in tracer.events()] == ["obs-bridge"]
+
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    spans, ops = [], []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name == "obs-bridge":
+                    spans.append((ev.start_ns, ev.start_ns + ev.duration_ns))
+                elif dict(ev.stats).get("hlo_module") == "jit_step":
+                    ops.append((ev.start_ns, ev.start_ns + ev.duration_ns))
+    (span_iv,) = spans
+    assert ops
+    assert all(span_iv[0] <= s and e <= span_iv[1] for s, e in ops)
 
 
 # ---------------------------------------------------------------------------

@@ -115,3 +115,24 @@ def test_mttkrp_fits_one_chip(one_chip, topo, mode):
     hbm = peaks_for(topo.devices[0].device_kind).hbm_bytes
     # two lane-padded gathered operands of nnz x 128 f32 dominate: 8.3 GB
     assert total < 0.6 * hbm, (total, hbm)
+
+
+def test_kernel_keeps_its_name_under_its_scope(one_chip):
+    """The scope that names the MTTKRP's reduction (``.../kernel/...``)
+    leaves the custom call named after the kernel, as a trace and a
+    profile's op table show it."""
+    blocks = 4
+    csf = CSF(mode=0, row_ids=_sds(one_chip, (blocks * BLOCK,), jnp.int32),
+              other_ids=_sds(one_chip, (blocks * BLOCK, 2), jnp.int32),
+              vals=_sds(one_chip, (blocks * BLOCK,), jnp.float32),
+              block_tile=_sds(one_chip, (blocks,), jnp.int32),
+              dims=(256, 300, 400), nnz=blocks * BLOCK, block=BLOCK,
+              row_tile=ROW_TILE)
+    factors = tuple(_sds(one_chip, (d, RANK), jnp.float32)
+                    for d in csf.dims)
+    text = jax.jit(ops.mttkrp, static_argnames=("interpret",)).lower(
+        csf, factors, interpret=False).compile().as_text()
+    (call,) = [line for line in text.splitlines()
+               if "tpu_custom_call" in line and " custom-call(" in line]
+    assert call.lstrip().startswith("%mttkrp")
+    assert "/kernel/mttkrp/pallas_call" in call
