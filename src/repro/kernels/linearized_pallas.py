@@ -26,13 +26,13 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.linearized import decode_field
 
-from .mttkrp_pallas import LANE
+from .mttkrp_pallas import LANE, segment_sum
 
 Array = jax.Array
 
 
 def _kernel(tile_map_ref, hi_ref, lo_ref, vals_ref, brows_ref, crows_ref,
-            out_ref, *, row_tile: int, block: int, offset: int, width: int):
+            out_ref, *, row_tile: int, offset: int, width: int):
     b = pl.program_id(0)
     tile = tile_map_ref[b]
     prev_tile = tile_map_ref[jnp.maximum(b - 1, 0)]
@@ -51,16 +51,9 @@ def _kernel(tile_map_ref, hi_ref, lo_ref, vals_ref, brows_ref, crows_ref,
         * brows_ref[0].astype(jnp.float32)
         * crows_ref[0].astype(jnp.float32)
     )
-    # one-hot segment matrix: S[m, n] = (rows[n] == tile*row_tile + m)
+    # collisions inside the block are summed by the MXU
     local = rows - tile * row_tile  # (BLOCK,), in [0, row_tile)
-    sel = (
-        jax.lax.broadcasted_iota(jnp.int32, (row_tile, block), 0)
-        == local[None, :]
-    )
-    out_ref[...] += jax.lax.dot(
-        sel.astype(jnp.float32), prod, precision=jax.lax.Precision.HIGHEST,
-        preferred_element_type=jnp.float32
-    )
+    out_ref[...] += segment_sum(local, prod, row_tile)
 
 
 def mttkrp_lin_pallas_call(
@@ -97,8 +90,8 @@ def mttkrp_lin_pallas_call(
         out_specs=pl.BlockSpec((row_tile, rp), lambda b, tm: (tm[b], 0)),
     )
     out = pl.pallas_call(
-        functools.partial(_kernel, row_tile=row_tile, block=block,
-                          offset=offset, width=width),
+        functools.partial(_kernel, row_tile=row_tile, offset=offset,
+                          width=width),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((num_row_tiles * row_tile, rp),
                                        jnp.float32),

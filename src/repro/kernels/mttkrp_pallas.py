@@ -16,17 +16,21 @@ mutexes; on a TPU we instead exploit the MXU:
     performs, in hardware, what SPLATT's mutex pool / atomics serialize.
     This is the paper's sync-vs-atomic finding taken to its TPU conclusion:
     conflict resolution as dense compute instead of synchronization;
-  * the elementwise Khatri-Rao product (vals x Brows x Crows) is fused into
-    the kernel so the (nnz x R) partial-product tensor never round-trips
-    HBM — only the gathered factor rows stream in.
+  * the elementwise Khatri-Rao product (vals x Brows x Crows) is formed in
+    the kernel, so the (nnz x R) partial product is never written to HBM.
+    The gathered factor rows it is formed from are: XLA gathers them,
+    lane-padded, into two (nnz x 128) arrays that the kernel streams in;
+  * the one-hot contraction is three single-pass bfloat16 matmuls, exact
+    as ``Precision.HIGHEST`` is (``segment_sum``).
 
 VMEM budget per grid step (defaults BLOCK=512, ROW_TILE=128, R padded 128):
   brows + crows: 2 x 512 x 128 x 4B = 512 KiB
-  one-hot + prod + out tile:   (128x512 + 512x128 + 128x128) x 4B = 576 KiB
+  prod + out tile: (512x128 + 128x128) x 4B = 320 KiB
 comfortably inside a v5e core's ~16 MiB VMEM with double buffering.
 
-The MXU work per step is a (128 x 512) @ (512 x 128) matmul — both dims
-hardware-aligned (multiples of 128 / 8 sublanes).
+The MXU work per step is twelve (128 x 128) @ (128 x 128) bfloat16 matmuls,
+three for each 128 non-zeros: every dim hardware-aligned (multiples of 128
+lanes and 16 bfloat16 sublanes).
 """
 from __future__ import annotations
 
@@ -42,8 +46,57 @@ Array = jax.Array
 LANE = 128  # TPU lane width: rank is padded to a multiple of this
 
 
+def _bf16_parts(x: Array) -> tuple[Array, Array, Array]:
+    """Split float32 ``x`` into bfloat16 ``hi``, ``mid`` and ``lo`` with
+    ``hi + mid + lo == x`` exactly, as ``Precision.HIGHEST`` splits an
+    operand: ``hi`` is ``x`` with the low 16 bits of its significand
+    cleared, exact in bfloat16 and exactly subtracted; ``mid`` is the same
+    of what is left, and ``lo`` what is left after that, at most 8
+    significant bits."""
+    def head(v):
+        bits = jax.lax.bitcast_convert_type(v, jnp.uint32)
+        return jax.lax.bitcast_convert_type(bits & jnp.uint32(0xFFFF0000),
+                                            jnp.float32)
+
+    hi = head(x)
+    rest = x - hi
+    mid = head(rest)
+    lo = rest - mid
+    return (hi.astype(jnp.bfloat16), mid.astype(jnp.bfloat16),
+            lo.astype(jnp.bfloat16))
+
+
+def segment_sum(local: Array, prod: Array, row_tile: int) -> Array:
+    """``out[m] = sum of prod[n] over the n with local[n] == m``, an
+    ``(row_tile, R)`` float32 tile: the one-hot segment matrix
+    ``S[m, n] = (local[n] == m)`` contracted with ``prod`` on the MXU.
+
+    Three single-pass bfloat16 matmuls, each accumulated in float32, do what
+    ``Precision.HIGHEST`` does on float32 operands, without its three
+    passes that multiply zeros: HIGHEST splits both operands into bfloat16
+    parts and sums six of their products, but ``S`` is 0/1, exact in
+    bfloat16, so its middle and low parts are zero.  ``prod``'s parts hold
+    its 24-bit significand exactly and each product of a part with 0 or 1
+    is exact, so ``S @ hi + S @ mid + S @ lo`` is HIGHEST's arithmetic: only
+    the order of the float32 accumulation differs.
+
+    The block is contracted ``LANE`` non-zeros at a time, the MXU's depth,
+    into one accumulator: the same products, with few enough values live
+    at once that the body all but stops spilling them to VMEM.
+    """
+    out = None
+    for start in range(0, local.shape[0], LANE):
+        rows = local[start:start + LANE]
+        iota = jax.lax.broadcasted_iota(jnp.int32, (row_tile, rows.shape[0]), 0)
+        sel = (iota == rows[None, :]).astype(jnp.bfloat16)
+        for part in _bf16_parts(prod[start:start + LANE]):
+            term = jax.lax.dot(sel, part, preferred_element_type=jnp.float32)
+            out = term if out is None else out + term
+    return out
+
+
 def _kernel(tile_map_ref, rows_ref, vals_ref, brows_ref, crows_ref, out_ref,
-            *, row_tile: int, block: int):
+            *, row_tile: int):
     b = pl.program_id(0)
     tile = tile_map_ref[b]
     prev_tile = tile_map_ref[jnp.maximum(b - 1, 0)]
@@ -59,19 +112,9 @@ def _kernel(tile_map_ref, rows_ref, vals_ref, brows_ref, crows_ref, out_ref,
         * brows_ref[0].astype(jnp.float32)
         * crows_ref[0].astype(jnp.float32)
     )
-    # one-hot segment matrix: S[m, n] = (rows[n] == tile*row_tile + m)
+    # collisions inside the block are summed by the MXU
     local = rows_ref[0, 0] - tile * row_tile  # (BLOCK,), in [0, row_tile)
-    sel = (
-        jax.lax.broadcasted_iota(jnp.int32, (row_tile, block), 0)
-        == local[None, :]
-    )
-    # MXU: collisions inside the block are summed by the matmul itself.
-    # HIGHEST: the one-hot operand is exact in bfloat16 but the products
-    # are not, and the MTTKRP is float32 end to end
-    out_ref[...] += jax.lax.dot(
-        sel.astype(jnp.float32), prod, precision=jax.lax.Precision.HIGHEST,
-        preferred_element_type=jnp.float32
-    )
+    out_ref[...] += segment_sum(local, prod, row_tile)
 
 
 def mttkrp_pallas_call(
@@ -105,7 +148,7 @@ def mttkrp_pallas_call(
         out_specs=pl.BlockSpec((row_tile, rp), lambda b, tm: (tm[b], 0)),
     )
     out = pl.pallas_call(
-        functools.partial(_kernel, row_tile=row_tile, block=block),
+        functools.partial(_kernel, row_tile=row_tile),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((num_row_tiles * row_tile, rp), jnp.float32),
         compiler_params=pltpu.CompilerParams(

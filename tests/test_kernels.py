@@ -6,7 +6,9 @@ import numpy as np
 import pytest
 
 from repro.core import random_sparse, build_csf_tiled, init_factors, cp_als, mttkrp
-from repro.kernels import ops, ref
+from repro.kernels import mttkrp_pallas, ops, ref
+from repro.kernels.linearized_pallas import mttkrp_lin_pallas_call
+from repro.kernels.mttkrp_pallas import mttkrp_pallas_call
 
 KEY = jax.random.PRNGKey(7)
 
@@ -94,6 +96,120 @@ def test_mttkrp_kernel_vs_segment_impl():
     got = ops.mttkrp(csfs[1], factors)
     want = mttkrp(build_csf(t, 1, block=64), factors, 1, impl="segment")
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-4)
+
+
+# ---------------------------------------------------------------------------
+# the one-hot contraction: three bfloat16 passes, as exact as HIGHEST
+# ---------------------------------------------------------------------------
+
+LIN_OFFSET, LIN_WIDTH = 28, 9  # a row field that straddles the two words
+
+
+def _colliding_blocks(nblocks=6, block=256, row_tile=64, rp=128):
+    """Two blocks a tile, each block's rows drawn from 8 of the tile's, and
+    values spanning 2**-20 to 2**20; the float64 segment sum of their
+    Khatri-Rao products is the answer."""
+    rng = np.random.default_rng(14)
+    tiles = np.repeat(np.arange(nblocks // 2), 2).astype(np.int32)
+    local = rng.integers(0, 8, size=(nblocks, block)) * 7 % row_tile
+    rows = (tiles[:, None] * row_tile + local).astype(np.int32)
+    vals = (rng.choice([-1.0, 1.0], size=(nblocks, block))
+            * 2.0 ** rng.uniform(-20, 20, size=(nblocks, block))
+            ).astype(np.float32)
+    b = rng.standard_normal((nblocks, block, rp)).astype(np.float32)
+    c = rng.standard_normal((nblocks, block, rp)).astype(np.float32)
+    want = np.zeros((tiles[-1] * row_tile + row_tile, rp))
+    np.add.at(want, rows.ravel(),
+              (vals.astype(np.float64)[..., None] * b * c).reshape(-1, rp))
+    return rows, vals, b, c, tiles, row_tile, want
+
+
+def _run_kernel(kernel, rows, vals, b, c, tiles, row_tile, interpret=True):
+    rows, vals = rows[:, None], vals[:, None]
+    num_row_tiles = int(tiles[-1]) + 1
+    if kernel == "csf":
+        return mttkrp_pallas_call(rows, vals, b, c, tiles,
+                                  num_row_tiles=num_row_tiles,
+                                  row_tile=row_tile, interpret=interpret)
+    # pack the row into a 64-bit index whose other bits are noise
+    noise = np.random.default_rng(1).integers(0, 2**63, size=rows.shape,
+                                              dtype=np.uint64)
+    field = np.uint64((1 << LIN_WIDTH) - 1) << np.uint64(LIN_OFFSET)
+    packed = ((noise & ~field)
+              | (rows.astype(np.uint64) << np.uint64(LIN_OFFSET)))
+    hi = (packed >> np.uint64(32)).astype(np.uint32)
+    lo = (packed & np.uint64(0xFFFFFFFF)).astype(np.uint32)
+    return mttkrp_lin_pallas_call(hi, lo, vals, b, c, tiles,
+                                  num_row_tiles=num_row_tiles,
+                                  row_tile=row_tile, offset=LIN_OFFSET,
+                                  width=LIN_WIDTH, interpret=interpret)
+
+
+@pytest.mark.parametrize("kernel", ["csf", "lin"])
+def test_segment_sum_matches_float64(kernel, monkeypatch):
+    """The three bfloat16 passes keep the products' 24-bit significands:
+    colliding rows over 40 binades match a float64 segment sum to float32
+    accuracy.  Dropping the low pass, or the middle and low, misses it: the
+    tolerance tells three passes from fewer."""
+    *args, want = _colliding_blocks()
+
+    def rel_err():
+        got = np.asarray(_run_kernel(kernel, *args), dtype=np.float64)
+        return np.linalg.norm(got - want) / np.linalg.norm(want)
+
+    assert rel_err() <= 2e-6
+    split = mttkrp_pallas._bf16_parts
+
+    def drop_passes(x, kept):
+        parts = split(x)
+        return parts[:kept] + tuple(jnp.zeros_like(p) for p in parts[kept:])
+
+    for kept in (1, 2):
+        monkeypatch.setattr(mttkrp_pallas, "_bf16_parts",
+                            lambda x, kept=kept: drop_passes(x, kept))
+        assert rel_err() > 2e-6, kept
+
+
+def test_bf16_parts_sum_exactly():
+    rng = np.random.default_rng(3)
+    x = jnp.asarray(rng.standard_normal(4096)
+                    * 2.0 ** rng.uniform(-30, 30, 4096), dtype=jnp.float32)
+    parts = mttkrp_pallas._bf16_parts(x)
+    assert [p.dtype for p in parts] == [jnp.bfloat16] * 3
+    total = sum(np.asarray(p, dtype=np.float64) for p in parts)
+    np.testing.assert_array_equal(total, np.asarray(x, dtype=np.float64))
+
+
+def _dot_generals(jaxpr):
+    """Every dot_general in ``jaxpr`` and the jaxprs nested in its params."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            found.append(eqn)
+        for param in eqn.params.values():
+            inner = getattr(param, "jaxpr", param)  # a ClosedJaxpr's Jaxpr
+            if hasattr(inner, "eqns"):
+                found += _dot_generals(inner)
+    return found
+
+
+@pytest.mark.parametrize("block", [64, 512])
+@pytest.mark.parametrize("kernel", ["csf", "lin"])
+def test_kernel_body_contracts_in_three_bf16_passes(kernel, block):
+    """The kernel body holds three single-pass bfloat16 matmuls for each
+    128 non-zeros of the block, accumulated in float32, and no HIGHEST
+    (six-pass) float32 matmul."""
+    *args, _ = _colliding_blocks(nblocks=2, block=block)
+    traced = jax.make_jaxpr(
+        lambda b, c: _run_kernel(kernel, args[0], args[1], b, c, args[4],
+                                 args[5], interpret=False))(args[2], args[3])
+    dots = _dot_generals(traced.jaxpr)
+    assert len(dots) == 3 * -(-block // mttkrp_pallas.LANE)
+    for eqn in dots:
+        assert [v.aval.dtype for v in eqn.invars] == [jnp.bfloat16] * 2
+        assert eqn.params["preferred_element_type"] == jnp.float32
+        assert eqn.params["precision"] in (
+            None, (jax.lax.Precision.DEFAULT,) * 2)
 
 
 # ---------------------------------------------------------------------------

@@ -65,28 +65,37 @@ def _csf(sharding, mode: int) -> CSF:
                dims=DIMS, nnz=NNZ, block=BLOCK, row_tile=ROW_TILE)
 
 
-def _kernel_operands(sharding, width: int, index_dtype=jnp.int32):
-    nb = max(BLOCKS)
-    return (_sds(sharding, (nb, 1, BLOCK), index_dtype),
-            _sds(sharding, (nb, BLOCK, width), jnp.float32),
+def _kernel_operands(sharding, width: int, index_dtype=jnp.int32,
+                     block: int = BLOCK):
+    nb = -(-max(BLOCKS) * BLOCK // block)  # yelp's largest mode, any block
+    return (_sds(sharding, (nb, 1, block), index_dtype),
+            _sds(sharding, (nb, block, width), jnp.float32),
             _sds(sharding, (nb,), jnp.int32))
 
 
-def test_mttkrp_kernel_compiles(one_chip):
-    rows, rows_f, tiles = _kernel_operands(one_chip, LANE)
+# the blockings the CPU tests run: the bfloat16 one-hot is tiled in 16
+# sublanes, the float32 operands in 8
+BLOCKINGS = [(64, 32), (128, 64), (256, 128), (BLOCK, ROW_TILE)]
+
+
+@pytest.mark.parametrize("block,row_tile", BLOCKINGS)
+def test_mttkrp_kernel_compiles(one_chip, block, row_tile):
+    rows, rows_f, tiles = _kernel_operands(one_chip, LANE, block=block)
     vals = _sds(one_chip, rows.shape, jnp.float32)
     call = jax.jit(lambda *a: mttkrp_pallas_call(
-        *a, num_row_tiles=-(-DIMS[2] // ROW_TILE), row_tile=ROW_TILE,
+        *a, num_row_tiles=-(-DIMS[2] // row_tile), row_tile=row_tile,
         interpret=False))
     hlo = call.lower(rows, vals, rows_f, rows_f, tiles).compile().as_text()
     assert "tpu_custom_call" in hlo
 
 
-def test_linearized_kernel_compiles(one_chip):
-    hi, rows_f, tiles = _kernel_operands(one_chip, LANE, jnp.uint32)
+@pytest.mark.parametrize("block,row_tile", BLOCKINGS)
+def test_linearized_kernel_compiles(one_chip, block, row_tile):
+    hi, rows_f, tiles = _kernel_operands(one_chip, LANE, jnp.uint32,
+                                         block=block)
     vals = _sds(one_chip, hi.shape, jnp.float32)
     call = jax.jit(lambda *a: mttkrp_lin_pallas_call(
-        *a, num_row_tiles=-(-DIMS[2] // ROW_TILE), row_tile=ROW_TILE,
+        *a, num_row_tiles=-(-DIMS[2] // row_tile), row_tile=row_tile,
         offset=0, width=17, interpret=False))
     hlo = call.lower(hi, hi, vals, rows_f, rows_f,
                      tiles).compile().as_text()
