@@ -126,11 +126,14 @@ def mttkrp_rowloop(t: SparseTensor, factors: Sequence[Array], mode: int) -> Arra
 def _krp_rows(
     inds: Array, factors: Sequence[Array], mode: int, vals: Array
 ) -> Array:
-    """prod[n, r] = vals[n] * prod_{m != mode} A_m[inds[n, m], r]."""
-    order = len(factors)
+    """prod[n, r] = vals[n] * prod_{m != mode} A_m[inds[n, m], r]; the
+    factors past the first two (order > 3) under ``khatri_rao``."""
+    others = [m for m in range(len(factors)) if m != mode]
     prod = vals[:, None].astype(factors[0].dtype)
-    for m in range(order):
-        if m != mode:
+    for m in others[:2]:
+        prod = prod * factors[m][inds[:, m]]
+    with jax.named_scope("khatri_rao"):
+        for m in others[2:]:
             prod = prod * factors[m][inds[:, m]]
     return prod
 
@@ -139,8 +142,11 @@ def _krp_rows_csf(csf: CSF, factors: Sequence[Array]) -> Array:
     """The CSF-workspace analogue of :func:`_krp_rows` (padding entries carry
     value 0, so their products are exact zeros)."""
     prod = csf.vals[:, None].astype(factors[0].dtype)
-    for i, m in enumerate(csf.other_modes):
+    for i, m in enumerate(csf.other_modes[:2]):
         prod = prod * factors[m][csf.other_ids[:, i]]
+    with jax.named_scope("khatri_rao"):
+        for i, m in enumerate(csf.other_modes[2:], start=2):
+            prod = prod * factors[m][csf.other_ids[:, i]]
     return prod
 
 
@@ -236,9 +242,12 @@ def mttkrp_linearized(ws, factors: Sequence[Array], mode: int) -> Array:
     resident memory and no re-sort."""
     lin = _require_lin(ws)
     with jax.named_scope("gather"):
+        others = [m for m in range(lin.order) if m != mode]
         prod = lin.vals[:, None].astype(factors[0].dtype)
-        for m in range(lin.order):
-            if m != mode:
+        for m in others[:2]:
+            prod = prod * factors[m][lin.decode(m)]
+        with jax.named_scope("khatri_rao"):
+            for m in others[2:]:
                 prod = prod * factors[m][lin.decode(m)]
     with jax.named_scope("kernel"):
         rows = lin.decode(mode)

@@ -66,7 +66,8 @@ def mttkrp(csf: CSF, factors: Sequence[Array], *,
     The factor-row gathers stay in XLA (HBM-bandwidth work XLA does well);
     the kernel fuses the Khatri-Rao multiply and the conflict-resolving
     one-hot matmul.  For order > 3 the extra factors' rows are pre-multiplied
-    into the second operand (associativity of the elementwise product).
+    into the second operand (associativity of the elementwise product),
+    under the scope ``gather/khatri_rao``.
     """
     if interpret is None:
         interpret = default_interpret()
@@ -75,9 +76,10 @@ def mttkrp(csf: CSF, factors: Sequence[Array], *,
     with jax.named_scope("gather"):
         brows = _gather_padded(factors[om[0]], csf.other_ids[:, 0])
         crows = _gather_padded(factors[om[1]], csf.other_ids[:, 1])
-        for i in range(2, len(om)):
-            crows = crows * _gather_padded(factors[om[i]],
-                                           csf.other_ids[:, i])
+        with jax.named_scope("khatri_rao"):
+            for i in range(2, len(om)):
+                crows = crows * _gather_padded(factors[om[i]],
+                                               csf.other_ids[:, i])
 
     nblocks, block = csf.num_blocks, csf.block
     rp = brows.shape[-1]
@@ -158,8 +160,9 @@ def mttkrp_lin(lin: Linearized, factors: Sequence[Array], mode: int, *,
     with jax.named_scope("gather"):
         brows = _gather_padded(factors[om[0]], lin.decode(om[0]))
         crows = _gather_padded(factors[om[1]], lin.decode(om[1]))
-        for m in om[2:]:
-            crows = crows * _gather_padded(factors[m], lin.decode(m))
+        with jax.named_scope("khatri_rao"):
+            for m in om[2:]:
+                crows = crows * _gather_padded(factors[m], lin.decode(m))
 
     nblocks, block = lin.num_blocks, lin.block
     rp = brows.shape[-1]

@@ -1,11 +1,14 @@
 """The program names its device work by layer: every op of the fused ALS
 sweep carries, in its ``op_name``, the scope of the layer it belongs to
-(``mttkrp/mode{n}`` with ``gather`` and ``kernel`` inside it, and
-``epilogue/mode{n}``), which is what a device trace is reduced by.  The
-scopes are metadata alone: without them the compiled program is the same.
+(``mttkrp/mode{n}`` with ``gather`` and ``kernel`` inside it, from order 4
+on ``khatri_rao`` inside ``gather``, and ``epilogue/mode{n}``), which is
+what a device trace is reduced by.  The scopes are metadata alone: without
+them the compiled program is the same.
 """
 import contextlib
 import re
+import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -16,13 +19,32 @@ from repro.core import cpals
 from repro.core.gram import gram
 from repro.plan import plan_decomposition
 
+sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
+from chipbench import scopes  # noqa: E402
+
 KEY = jax.random.PRNGKey(3)
 RANK = 4
+DIMS = {3: (24, 20, 16), 4: (10, 9, 12, 6)}
 
 
-def _compiled_sweep(impl: str) -> str:
+@pytest.fixture(autouse=True)
+def no_persistent_cache():
+    """Compile every sweep anew.  A test that runs the CLI in this process
+    leaves JAX's persistent cache on, and its key leaves out op metadata:
+    the unscoped sweep would be handed the scoped one's compiled text."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compiled_sweep(impl: str, order: int = 3) -> str:
     """The optimized text of one fused sweep at a small CSF workspace."""
-    t = exact_lowrank_tensor((24, 20, 16), RANK, KEY)
+    t = exact_lowrank_tensor(DIMS[order], RANK, KEY)
     plan = plan_decomposition(t, impl, rank=RANK, with_stats=False)
     ws = cpals.build_workspace(t, plan)
     factors = cpals.init_factors(t.dims, RANK, KEY)
@@ -64,3 +86,24 @@ def test_scopes_change_nothing_but_metadata(impl, monkeypatch):
     plain = _compiled_sweep(impl)
     assert "/mttkrp/mode0/" in scoped and "/mttkrp/mode0/" not in plain
     assert _without_metadata(scoped) == _without_metadata(plain)
+
+
+@pytest.mark.parametrize("impl", ["pallas", "segment", "gather_scatter",
+                                  "linearized", "linearized_pallas"])
+def test_order4_chain_product_is_named_inside_gather(impl):
+    """From order 4 on, the rows of the factors past the first two and
+    their product carry ``gather/khatri_rao``; a trace reads them as the
+    mode's gather."""
+    names = set(re.findall(r'op_name="([^"]*)"',
+                           _compiled_sweep(impl, order=4)))
+    for n in range(4):
+        chain = [name for name in names if re.search(
+            rf"/mttkrp/mode{n}/(.+/)?gather/khatri_rao/", name)]
+        assert chain, n
+        assert {scopes.scope_of(name) for name in chain} == {
+            f"mttkrp/mode{n}/gather"}
+
+
+@pytest.mark.parametrize("impl", ["pallas", "segment"])
+def test_order3_has_no_chain_product(impl):
+    assert "khatri_rao" not in _compiled_sweep(impl)

@@ -55,14 +55,14 @@ def _sds(sharding, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _csf(sharding, mode: int) -> CSF:
-    pnnz = BLOCKS[mode] * BLOCK
+def _csf(sharding, mode: int, dims=DIMS, nnz=NNZ, blocks=BLOCKS) -> CSF:
+    pnnz = blocks[mode] * BLOCK
     return CSF(mode=mode,
                row_ids=_sds(sharding, (pnnz,), jnp.int32),
-               other_ids=_sds(sharding, (pnnz, 2), jnp.int32),
+               other_ids=_sds(sharding, (pnnz, len(dims) - 1), jnp.int32),
                vals=_sds(sharding, (pnnz,), jnp.float32),
-               block_tile=_sds(sharding, (BLOCKS[mode],), jnp.int32),
-               dims=DIMS, nnz=NNZ, block=BLOCK, row_tile=ROW_TILE)
+               block_tile=_sds(sharding, (blocks[mode],), jnp.int32),
+               dims=dims, nnz=nnz, block=BLOCK, row_tile=ROW_TILE)
 
 
 def _kernel_operands(sharding, width: int, index_dtype=jnp.int32,
@@ -124,6 +124,43 @@ def test_mttkrp_fits_one_chip(one_chip, topo, mode):
     hbm = peaks_for(topo.devices[0].device_kind).hbm_bytes
     # two lane-padded gathered operands of nnz x 128 f32 dominate: 8.3 GB
     assert total < 0.6 * hbm, (total, hbm)
+
+
+# The fused sweep at the benchmark's shapes: yelp's, and FROSTT enron's dims
+# at 8,000,000 drawn non-zeros (``chipbench/configs/enron.json``), sorted
+# into the blocks per mode of the harness's draw on the CPU; and the most
+# the whole program may take: at yelp's shape a little over its rehearsed
+# 8.75 GB, at enron's three quarters of one chip's 16 GB.
+ENRON_DIMS = (6_066, 5_699, 244_268, 1_176)
+SWEEPS = {
+    "yelp": (DIMS, NNZ, BLOCKS, 8.84e9),
+    "enron": (ENRON_DIMS, 8_000_000, (15_651, 15_647, 16_613, 15_630),
+              12.0e9),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(SWEEPS))
+def test_fused_sweep_fits_one_chip(one_chip, shape, monkeypatch):
+    """The whole fused ALS sweep at rank 35, as the benchmark's window runs
+    it (``cpals._iteration``, factor and gram buffers donated), within its
+    share of one chip's HBM."""
+    from repro.core import cpals
+
+    dims, nnz, blocks, most = SWEEPS[shape]
+    monkeypatch.setattr(ops, "default_interpret", lambda: False)
+    jax.clear_caches()  # trace the kernels' jits anew, compiled
+    ws = [_csf(one_chip, m, dims, nnz, blocks) for m in range(len(dims))]
+    factors = tuple(_sds(one_chip, (d, RANK), jnp.float32) for d in dims)
+    grams = tuple(_sds(one_chip, (RANK, RANK), jnp.float32) for _ in dims)
+    compiled = cpals._iteration_jit(True).lower(
+        ws, factors, grams, _sds(one_chip, (), jnp.float32),
+        impls=("pallas",) * len(dims), norm_kind="2",
+        with_fit=True).compile()
+    jax.clear_caches()
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert total <= most, (shape, total)
 
 
 def test_kernel_keeps_its_name_under_its_scope(one_chip):
