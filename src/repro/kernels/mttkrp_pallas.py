@@ -16,17 +16,25 @@ mutexes; on a TPU we instead exploit the MXU:
     performs, in hardware, what SPLATT's mutex pool / atomics serialize.
     This is the paper's sync-vs-atomic finding taken to its TPU conclusion:
     conflict resolution as dense compute instead of synchronization;
-  * the elementwise Khatri-Rao product (vals x Brows x Crows) is formed in
-    the kernel, so the (nnz x R) partial product is never written to HBM.
-    The gathered factor rows it is formed from are: XLA gathers them,
-    lane-padded, into two (nnz x 128) arrays that the kernel streams in;
+  * the elementwise Khatri-Rao product (vals x Brows x Crows [x Drows ...])
+    is formed in the kernel from every gathered operand, so neither the
+    (nnz x R) partial product nor a product of two operands is written to
+    HBM.  The gathered factor rows it is formed from are: XLA gathers them,
+    lane-padded, into one (nnz x 128) array per other mode that the kernel
+    streams in;
   * the one-hot contraction is three single-pass bfloat16 matmuls, exact
-    as ``Precision.HIGHEST`` is (``segment_sum``).
+    as ``Precision.HIGHEST`` is (``segment_sum``);
+  * a call may carry the output in: given ``carry``, the output aliases it
+    and a tile's first visit loads the carried tile instead of zeros, so a
+    mode's blocks can go through the kernel in consecutive pieces (a tile
+    whose blocks straddle two calls sums across both, tiles a call never
+    visits keep what they held).
 
-VMEM budget per grid step (defaults BLOCK=512, ROW_TILE=128, R padded 128):
-  brows + crows: 2 x 512 x 128 x 4B = 512 KiB
-  prod + out tile: (512x128 + 128x128) x 4B = 320 KiB
-comfortably inside a v5e core's ~16 MiB VMEM with double buffering.
+VMEM budget per grid step (defaults BLOCK=512, ROW_TILE=128, R padded 128),
+with three gathered operands (order 4):
+  operands: 3 x 512 x 128 x 4B = 768 KiB, 1.5 MiB double-buffered
+  prod + out tile (+ carried tile): (512x128 + 2 x 128x128) x 4B = 384 KiB
+comfortably inside a v5e core's ~16 MiB VMEM.
 
 The MXU work per step is twelve (128 x 128) @ (128 x 128) bfloat16 matmuls,
 three for each 128 non-zeros: every dim hardware-aligned (multiples of 128
@@ -35,6 +43,7 @@ lanes and 16 bfloat16 sublanes).
 from __future__ import annotations
 
 import functools
+from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -95,8 +104,11 @@ def segment_sum(local: Array, prod: Array, row_tile: int) -> Array:
     return out
 
 
-def _kernel(tile_map_ref, rows_ref, vals_ref, brows_ref, crows_ref, out_ref,
-            *, row_tile: int):
+def _kernel(tile_map_ref, rows_ref, vals_ref, *refs, row_tile: int,
+            num_operands: int, carried: bool):
+    operand_refs = refs[:num_operands]
+    carry_ref = refs[num_operands] if carried else None
+    out_ref = refs[-1]
     b = pl.program_id(0)
     tile = tile_map_ref[b]
     prev_tile = tile_map_ref[jnp.maximum(b - 1, 0)]
@@ -104,14 +116,20 @@ def _kernel(tile_map_ref, rows_ref, vals_ref, brows_ref, crows_ref, out_ref,
 
     @pl.when(is_first_visit)
     def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
+        if carried:
+            out_ref[...] = carry_ref[...]
+        else:
+            out_ref[...] = jnp.zeros_like(out_ref)
 
-    # fused Khatri-Rao partial product: (BLOCK, R)
-    prod = (
-        vals_ref[0, 0][:, None].astype(jnp.float32)
-        * brows_ref[0].astype(jnp.float32)
-        * crows_ref[0].astype(jnp.float32)
-    )
+    # fused Khatri-Rao partial product, (BLOCK, R): (vals * op0) * (op1 *
+    # op2 * ...), which at order 3 is (vals * brows) * crows
+    prod = (vals_ref[0, 0][:, None].astype(jnp.float32)
+            * operand_refs[0][0].astype(jnp.float32))
+    if num_operands > 1:
+        others = operand_refs[1][0].astype(jnp.float32)
+        for ref in operand_refs[2:]:
+            others = others * ref[0].astype(jnp.float32)
+        prod = prod * others
     # collisions inside the block are summed by the MXU
     local = rows_ref[0, 0] - tile * row_tile  # (BLOCK,), in [0, row_tile)
     out_ref[...] += segment_sum(local, prod, row_tile)
@@ -120,40 +138,53 @@ def _kernel(tile_map_ref, rows_ref, vals_ref, brows_ref, crows_ref, out_ref,
 def mttkrp_pallas_call(
     rows: Array,        # (nblocks, 1, BLOCK) int32, tile-aligned sorted rows
     vals: Array,        # (nblocks, 1, BLOCK)
-    brows: Array,       # (nblocks, BLOCK, RP) gathered factor rows
-    crows: Array,       # (nblocks, BLOCK, RP) gathered (and pre-multiplied
-                        #  for order > 3) remaining factor rows
+    operands: Sequence[Array],  # each (nblocks, BLOCK, RP): gathered factor
+                                #  rows, one array per other mode
     block_tile: Array,  # (nblocks,) int32 non-decreasing block -> tile map
     *,
     num_row_tiles: int,
     row_tile: int,
     interpret: bool,
+    carry: Optional[Array] = None,  # (num_row_tiles * row_tile, RP) float32
+                                    #  output of an earlier call, aliased
 ) -> Array:
+    """``out[r] = sum over the non-zeros of row r of vals * op0 * op1 ...``,
+    a ``(num_row_tiles * row_tile, RP)`` float32 array.  Without ``carry``
+    every visited tile starts from zeros; with it the output is ``carry``'s
+    buffer, each visited tile starts from its carried value and the tiles
+    not visited are ``carry``'s."""
     nblocks, _, block = rows.shape
-    rp = brows.shape[-1]
+    rp = operands[0].shape[-1]
     if rp % LANE:
         raise ValueError(f"rank must be padded to {LANE}, got {rp}")
 
+    # (1, 1, block): the last two block dims equal the array's (1, block),
+    # which the TPU lowering requires of a 1-row block
+    index_spec = pl.BlockSpec((1, 1, block), lambda b, tm: (b, 0, 0))
+    operand_spec = pl.BlockSpec((1, block, rp), lambda b, tm: (b, 0, 0))
+    tile_spec = pl.BlockSpec((row_tile, rp), lambda b, tm: (tm[b], 0))
+    in_specs = [index_spec, index_spec] + [operand_spec] * len(operands)
+    args = [block_tile, rows, vals, *operands]
+    aliases = {}
+    if carry is not None:
+        in_specs.append(tile_spec)
+        aliases = {len(args): 0}  # counted with the scalar-prefetch operand
+        args.append(carry)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(nblocks,),
-        in_specs=[
-            # (1, 1, block): the last two block dims equal the array's
-            # (1, block), which the TPU lowering requires of a 1-row block
-            pl.BlockSpec((1, 1, block), lambda b, tm: (b, 0, 0)),
-            pl.BlockSpec((1, 1, block), lambda b, tm: (b, 0, 0)),
-            pl.BlockSpec((1, block, rp), lambda b, tm: (b, 0, 0)),
-            pl.BlockSpec((1, block, rp), lambda b, tm: (b, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((row_tile, rp), lambda b, tm: (tm[b], 0)),
+        in_specs=in_specs,
+        out_specs=tile_spec,
     )
-    out = pl.pallas_call(
-        functools.partial(_kernel, row_tile=row_tile),
+    return pl.pallas_call(
+        functools.partial(_kernel, row_tile=row_tile,
+                          num_operands=len(operands),
+                          carried=carry is not None),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((num_row_tiles * row_tile, rp), jnp.float32),
+        input_output_aliases=aliases,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),  # sequential: accumulation
         ),
         interpret=interpret,
-    )(block_tile, rows, vals, brows, crows)
-    return out
+    )(*args)

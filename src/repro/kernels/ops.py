@@ -49,13 +49,31 @@ def _pad_lanes(a: Array) -> Array:
     return jnp.pad(a, pad)
 
 
+def _padded(factor: Array) -> Array:
+    """``factor`` padded to the lane width, for its rows to be gathered
+    from.  The barrier keeps the pad before the gather: XLA would otherwise
+    move it after, and on a TPU an (nnz, R) array is laid out in 128-lane
+    tiles anyway: the gather and its padded copy would be two nnz x 128
+    buffers instead of one (at yelp's scale, 4.2 GB each)."""
+    return jax.lax.optimization_barrier(_pad_lanes(factor))
+
+
 def _gather_padded(factor: Array, ids: Array) -> Array:
     """Rows ``factor[ids]`` padded to the lane width, gathered from the
-    padded factor.  XLA would otherwise move the pad after the gather, and
-    on a TPU an (nnz, R) array is laid out in 128-lane tiles anyway: the
-    gather and its padded copy would be two nnz x 128 buffers instead of
-    one (at yelp's scale, 4.2 GB each)."""
-    return jax.lax.optimization_barrier(_pad_lanes(factor))[ids]
+    padded factor (``_padded``)."""
+    return _padded(factor)[ids]
+
+
+def _block_ranges(num_blocks: int, order: int) -> list[tuple[int, int]]:
+    """The consecutive ranges of a mode's blocks that go through the MTTKRP
+    kernel one call each: ``ceil((order - 1) / 2)`` of equal size, the last
+    one shorter.  One range at order 3; two at orders 4 and 5, so that a
+    range's ``order - 1`` gathered operands take no more HBM than two of
+    the whole mode's."""
+    pieces = -(-(order - 1) // 2)
+    size = -(-num_blocks // pieces)
+    return [(lo, min(lo + size, num_blocks))
+            for lo in range(0, num_blocks, size)]
 
 
 @partial(jax.jit, static_argnames=("interpret",))
@@ -64,38 +82,56 @@ def mttkrp(csf: CSF, factors: Sequence[Array], *,
     """MTTKRP for the mode ``csf`` was built for.  Returns (num_rows, R).
 
     The factor-row gathers stay in XLA (HBM-bandwidth work XLA does well);
-    the kernel fuses the Khatri-Rao multiply and the conflict-resolving
-    one-hot matmul.  For order > 3 the extra factors' rows are pre-multiplied
-    into the second operand (associativity of the elementwise product),
-    under the scope ``gather/khatri_rao``.
+    the kernel fuses the Khatri-Rao multiply of every gathered operand and
+    the conflict-resolving one-hot matmul.  From order 4 on, the rows of the
+    factors past the first two are gathered under the scope
+    ``gather/khatri_rao``, and the mode's blocks go through the kernel in
+    consecutive ranges (``_block_ranges``), the output carried from call to
+    call: each range's gathered rows are live only for its own call.
     """
     if interpret is None:
         interpret = default_interpret()
     rank = factors[0].shape[1]
     om = csf.other_modes
-    with jax.named_scope("gather"):
-        brows = _gather_padded(factors[om[0]], csf.other_ids[:, 0])
-        crows = _gather_padded(factors[om[1]], csf.other_ids[:, 1])
-        with jax.named_scope("khatri_rao"):
-            for i in range(2, len(om)):
-                crows = crows * _gather_padded(factors[om[i]],
-                                               csf.other_ids[:, i])
-
     nblocks, block = csf.num_blocks, csf.block
-    rp = brows.shape[-1]
-    # the innermost scope names the custom call, in the compiled program
-    # and in a trace: keep it the kernel's name
-    with jax.named_scope("kernel"), jax.named_scope("mttkrp"):
-        out = mttkrp_pallas_call(
-            csf.row_ids.reshape(nblocks, 1, block),
-            csf.vals.reshape(nblocks, 1, block),
-            brows.reshape(nblocks, block, rp),
-            crows.reshape(nblocks, block, rp),
-            csf.block_tile,
-            num_row_tiles=csf.num_row_tiles,
-            row_tile=csf.row_tile,
-            interpret=interpret,
-        )
+    ranges = _block_ranges(nblocks, csf.order)
+    whole = len(ranges) == 1
+    padded = {}  # each factor lane-padded once a mode, on its first gather
+
+    def gather(i: int, ids: Array) -> Array:
+        if i not in padded:
+            padded[i] = _padded(factors[om[i]])
+        return padded[i][ids[:, i]]
+
+    out = None
+    for lo, hi in ranges:
+        ids = csf.other_ids if whole else csf.other_ids[lo * block:hi * block]
+        with jax.named_scope("gather"):
+            operands = [gather(i, ids) for i in range(2)]
+            with jax.named_scope("khatri_rao"):
+                operands += [gather(i, ids) for i in range(2, len(om))]
+        rp = operands[0].shape[-1]
+        if out is None and not whole:
+            with jax.named_scope("kernel"):
+                out = jnp.zeros((csf.num_row_tiles * csf.row_tile, rp),
+                                jnp.float32)
+        # the innermost scope names the custom call, in the compiled program
+        # and in a trace: keep it the kernel's name
+        with jax.named_scope("kernel"), jax.named_scope("mttkrp"):
+            rows = csf.row_ids.reshape(nblocks, 1, block)
+            vals = csf.vals.reshape(nblocks, 1, block)
+            tiles = csf.block_tile
+            if not whole:
+                rows, vals, tiles = rows[lo:hi], vals[lo:hi], tiles[lo:hi]
+            out = mttkrp_pallas_call(
+                rows, vals,
+                [op.reshape(hi - lo, block, rp) for op in operands],
+                tiles,
+                num_row_tiles=csf.num_row_tiles,
+                row_tile=csf.row_tile,
+                interpret=interpret,
+                carry=out,
+            )
     return out[: csf.num_rows, :rank].astype(factors[0].dtype)
 
 
@@ -108,9 +144,9 @@ def ttmc(csf: CSF, factors: Sequence[Array], *,
     one-hot segment-matmul reused verbatim: the row-wise Kronecker chain of
     the other modes' factor rows is formed XLA-side (it is just a reshaped
     outer product — HBM-bandwidth work, like the factor gathers) and fed in
-    as the first operand with an all-ones second operand, so the fused
-    ``vals * brows * crows`` multiply and the conflict-resolving one-hot
-    matmul run unchanged at the wider Kronecker rank.
+    as the kernel's one operand, so the fused ``vals * kron`` multiply and
+    the conflict-resolving one-hot matmul run unchanged at the wider
+    Kronecker rank.
     """
     if interpret is None:
         interpret = default_interpret()
@@ -126,8 +162,7 @@ def ttmc(csf: CSF, factors: Sequence[Array], *,
     out = mttkrp_pallas_call(
         csf.row_ids.reshape(nblocks, 1, block),
         csf.vals.reshape(nblocks, 1, block),
-        kron.reshape(nblocks, block, rp),
-        jnp.ones((nblocks, block, rp), dtype=kron.dtype),
+        [kron.reshape(nblocks, block, rp)],
         csf.block_tile,
         num_row_tiles=csf.num_row_tiles,
         row_tile=csf.row_tile,

@@ -1,5 +1,7 @@
 """Per-kernel allclose sweeps: Pallas (interpret=True) vs pure-jnp oracle,
 across shapes / ranks / dtypes / block sizes, plus CP-ALS integration."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -99,6 +101,126 @@ def test_mttkrp_kernel_vs_segment_impl():
 
 
 # ---------------------------------------------------------------------------
+# every gathered operand into the kernel; a mode's blocks in ranges
+# ---------------------------------------------------------------------------
+
+def _summed_as_the_kernel_sums(csf, factors, carry=None):
+    """The MTTKRP of ``csf``'s mode in jnp and numpy, as one kernel call
+    over all the mode's blocks computes it: the Khatri-Rao rows
+    ``vals * B * (C * D [* E])``, lane-padded, each block's rows summed into
+    its tile by the kernel's ``segment_sum``, and the blocks' sums added
+    into their tiles in float32, block after block, onto zeros (or onto
+    ``carry``'s tile, where given).  Returns the whole padded output."""
+    block, row_tile = csf.block, csf.row_tile
+    gathered = [ops._pad_lanes(factors[m][csf.other_ids[:, i]])
+                for i, m in enumerate(csf.other_modes)]
+    others = gathered[1]
+    for g in gathered[2:]:
+        others = others * g
+    prod = csf.vals[:, None] * gathered[0] * others
+    nblocks, rp = csf.num_blocks, prod.shape[-1]
+    tiles = np.asarray(csf.block_tile)
+    local = csf.row_ids.reshape(nblocks, block) - csf.block_tile[:, None] * row_tile
+    sums = np.asarray(jax.jit(lambda local, prod: jax.lax.map(
+        lambda a: mttkrp_pallas.segment_sum(a[0], a[1], row_tile),
+        (local, prod)))(local, prod.reshape(nblocks, block, rp)))
+    out = (np.zeros((csf.num_row_tiles * row_tile, rp), np.float32)
+           if carry is None else np.array(carry))
+    for b, tile in enumerate(tiles):
+        rows = slice(tile * row_tile, (tile + 1) * row_tile)
+        if b > 0 and tiles[b - 1] == tile:
+            out[rows] = out[rows] + sums[b]
+        else:
+            start = np.zeros_like(sums[b]) if carry is None else out[rows]
+            out[rows] = start + sums[b]
+    return out
+
+
+def _straddles(csf) -> bool:
+    """Whether the output tile of the last block of ``csf``'s first kernel
+    call is also the tile of the second call's first block."""
+    (_, end), *_ = ops._block_ranges(csf.num_blocks, csf.order)
+    tiles = np.asarray(csf.block_tile)
+    return bool(tiles[end - 1] == tiles[end])
+
+
+@pytest.mark.parametrize("dims,nnz,mode,straddles", [
+    ((40, 15, 2000, 10), 3000, 0, True),     # the boundary inside a tile
+    ((40, 15, 2000, 10), 3000, 2, False),    # the boundary between tiles
+    ((24, 8, 1500, 7, 6), 2500, 0, True),
+    ((24, 8, 1500, 7, 6), 2500, 2, False),
+])
+def test_mttkrp_in_block_ranges_is_the_one_call_sum(dims, nnz, mode, straddles):
+    """From order 4 on a mode's blocks go through the kernel in two calls,
+    the output carried from one to the next, and every gathered operand is
+    multiplied in the kernel: the result is the single call's over the
+    products ``vals * B * (C * D [* E])``, to the last bit, a tile whose
+    blocks straddle the two calls included."""
+    t, csfs, factors = make_case(dims, nnz, rank=8, skew=1.0, block=64,
+                                 row_tile=8)
+    csf = csfs[mode]
+    assert len(ops._block_ranges(csf.num_blocks, t.order)) == 2
+    assert _straddles(csf) == straddles
+    got = np.asarray(ops.mttkrp(csf, factors))
+    want = _summed_as_the_kernel_sums(csf, factors)[:csf.num_rows, :8]
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("mode", [0, 1, 2])
+def test_order3_mttkrp_is_one_call_over_two_operands(mode):
+    t, csfs, factors = make_case((50, 40, 30), 2000, rank=8, skew=1.0,
+                                 block=64, row_tile=8)
+    assert ops._block_ranges(csfs[mode].num_blocks, 3) == [
+        (0, csfs[mode].num_blocks)]
+    got = np.asarray(ops.mttkrp(csfs[mode], factors))
+    want = _summed_as_the_kernel_sums(csfs[mode], factors)
+    np.testing.assert_array_equal(got, want[:csfs[mode].num_rows, :8])
+
+
+@pytest.mark.parametrize("num_blocks,order,ranges", [
+    (10, 3, [(0, 10)]),
+    (10, 4, [(0, 5), (5, 10)]),
+    (11, 4, [(0, 6), (6, 11)]),
+    (11, 5, [(0, 6), (6, 11)]),
+    (1, 4, [(0, 1)]),
+    (7, 6, [(0, 3), (3, 6), (6, 7)]),
+])
+def test_block_ranges(num_blocks, order, ranges):
+    assert ops._block_ranges(num_blocks, order) == ranges
+
+
+def test_kernel_call_adds_onto_its_carry():
+    """With ``carry`` a call's visited tiles start from their carried
+    values and the tiles it never visits keep them."""
+    t, csfs, factors = make_case((40, 15, 2000, 10), 3000, rank=8,
+                                 block=64, row_tile=8)
+    csf = csfs[2]
+    lo, hi = 40, 90
+    rp = mttkrp_pallas.LANE
+    carry = jax.random.normal(KEY, (csf.num_row_tiles * csf.row_tile, rp))
+    part = dataclasses.replace(
+        csf, row_ids=csf.row_ids[lo * 64:hi * 64],
+        other_ids=csf.other_ids[lo * 64:hi * 64],
+        vals=csf.vals[lo * 64:hi * 64], block_tile=csf.block_tile[lo:hi])
+    operands = [ops._pad_lanes(factors[m][part.other_ids[:, i]])
+                .reshape(hi - lo, 64, rp)
+                for i, m in enumerate(part.other_modes)]
+    got = mttkrp_pallas_call(
+        part.row_ids.reshape(hi - lo, 1, 64),
+        part.vals.reshape(hi - lo, 1, 64), operands, part.block_tile,
+        num_row_tiles=csf.num_row_tiles, row_tile=csf.row_tile,
+        interpret=True, carry=carry)
+    want = _summed_as_the_kernel_sums(part, factors, carry=carry)
+    np.testing.assert_array_equal(np.asarray(got), want)
+    visited = np.zeros(csf.num_row_tiles, bool)
+    visited[np.asarray(part.block_tile)] = True
+    assert 0 < visited.sum() < csf.num_row_tiles
+    kept = np.repeat(~visited, csf.row_tile)
+    np.testing.assert_array_equal(np.asarray(got)[kept],
+                                  np.asarray(carry)[kept])
+
+
+# ---------------------------------------------------------------------------
 # the one-hot contraction: three bfloat16 passes, as exact as HIGHEST
 # ---------------------------------------------------------------------------
 
@@ -128,7 +250,7 @@ def _run_kernel(kernel, rows, vals, b, c, tiles, row_tile, interpret=True):
     rows, vals = rows[:, None], vals[:, None]
     num_row_tiles = int(tiles[-1]) + 1
     if kernel == "csf":
-        return mttkrp_pallas_call(rows, vals, b, c, tiles,
+        return mttkrp_pallas_call(rows, vals, (b, c), tiles,
                                   num_row_tiles=num_row_tiles,
                                   row_tile=row_tile, interpret=interpret)
     # pack the row into a 64-bit index whose other bits are noise

@@ -9,6 +9,9 @@ compilable between chip runs.
 yelp at its published scale (``PAPER_DATASETS``: 8.0M non-zeros) sorts into
 at most 15,902 blocks of 512 per mode; the paper's rank 35 pads to 128 lanes.
 """
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -82,9 +85,9 @@ BLOCKINGS = [(64, 32), (128, 64), (256, 128), (BLOCK, ROW_TILE)]
 def test_mttkrp_kernel_compiles(one_chip, block, row_tile):
     rows, rows_f, tiles = _kernel_operands(one_chip, LANE, block=block)
     vals = _sds(one_chip, rows.shape, jnp.float32)
-    call = jax.jit(lambda *a: mttkrp_pallas_call(
-        *a, num_row_tiles=-(-DIMS[2] // row_tile), row_tile=row_tile,
-        interpret=False))
+    call = jax.jit(lambda rows, vals, b, c, tiles: mttkrp_pallas_call(
+        rows, vals, (b, c), tiles, num_row_tiles=-(-DIMS[2] // row_tile),
+        row_tile=row_tile, interpret=False))
     hlo = call.lower(rows, vals, rows_f, rows_f, tiles).compile().as_text()
     assert "tpu_custom_call" in hlo
 
@@ -139,28 +142,72 @@ SWEEPS = {
 }
 
 
-@pytest.mark.parametrize("shape", sorted(SWEEPS))
-def test_fused_sweep_fits_one_chip(one_chip, shape, monkeypatch):
-    """The whole fused ALS sweep at rank 35, as the benchmark's window runs
-    it (``cpals._iteration``, factor and gram buffers donated), within its
-    share of one chip's HBM."""
+@pytest.fixture(scope="module")
+def fused_sweep(one_chip):
+    """The whole fused ALS sweep at rank 35 for a shape of ``SWEEPS``, as
+    the benchmark's window runs it (``cpals._iteration``, factor and gram
+    buffers donated), compiled once for the module."""
     from repro.core import cpals
 
-    dims, nnz, blocks, most = SWEEPS[shape]
-    monkeypatch.setattr(ops, "default_interpret", lambda: False)
-    jax.clear_caches()  # trace the kernels' jits anew, compiled
-    ws = [_csf(one_chip, m, dims, nnz, blocks) for m in range(len(dims))]
-    factors = tuple(_sds(one_chip, (d, RANK), jnp.float32) for d in dims)
-    grams = tuple(_sds(one_chip, (RANK, RANK), jnp.float32) for _ in dims)
-    compiled = cpals._iteration_jit(True).lower(
-        ws, factors, grams, _sds(one_chip, (), jnp.float32),
-        impls=("pallas",) * len(dims), norm_kind="2",
-        with_fit=True).compile()
-    jax.clear_caches()
-    m = compiled.memory_analysis()
+    compiled = {}
+
+    def compile_sweep(shape):
+        if shape not in compiled:
+            dims, nnz, blocks, _ = SWEEPS[shape]
+            ws = [_csf(one_chip, m, dims, nnz, blocks)
+                  for m in range(len(dims))]
+            factors = tuple(_sds(one_chip, (d, RANK), jnp.float32)
+                            for d in dims)
+            grams = tuple(_sds(one_chip, (RANK, RANK), jnp.float32)
+                          for _ in dims)
+            with pytest.MonkeyPatch.context() as mp:
+                mp.setattr(ops, "default_interpret", lambda: False)
+                jax.clear_caches()  # trace the kernels' jits anew, compiled
+                compiled[shape] = cpals._iteration_jit(True).lower(
+                    ws, factors, grams, _sds(one_chip, (), jnp.float32),
+                    impls=("pallas",) * len(dims), norm_kind="2",
+                    with_fit=True).compile()
+                jax.clear_caches()
+        return compiled[shape]
+
+    return compile_sweep
+
+
+@pytest.mark.parametrize("shape", sorted(SWEEPS))
+def test_fused_sweep_fits_one_chip(fused_sweep, shape):
+    """The whole fused ALS sweep within its share of one chip's HBM."""
+    m = fused_sweep(shape).memory_analysis()
     total = (m.argument_size_in_bytes + m.output_size_in_bytes
              + m.temp_size_in_bytes - m.alias_size_in_bytes)
-    assert total <= most, (shape, total)
+    assert total <= SWEEPS[shape][3], (shape, total)
+
+
+_GATHERED = re.compile(rf"f32\[\d+,{BLOCK},{LANE}\]")
+
+
+@pytest.mark.parametrize("shape,calls,operands", [("yelp", 1, 2),
+                                                   ("enron", 2, 3)])
+def test_fused_sweep_multiplies_every_operand_in_the_kernel(
+        fused_sweep, shape, calls, operands):
+    """Each mode's MTTKRP is ``calls`` kernel calls, each streaming in one
+    gathered operand per other mode, and no multiply over a non-zero
+    stream (an array with more rows than the longest factor) is left
+    outside them: order 4 forms the whole Khatri-Rao product in the
+    kernel, in two ranges of the mode's blocks."""
+    dims = SWEEPS[shape][0]
+    text = fused_sweep(shape).as_text()
+    per_mode = {}
+    for line in text.splitlines():
+        if "tpu_custom_call" in line and " custom-call(" in line:
+            mode = re.search(r"/mttkrp/mode(\d+)/", line).group(1)
+            layouts = re.search(r"operand_layout_constraints=\{(.*?)\}\}",
+                                line).group(1)
+            per_mode.setdefault(int(mode), []).append(
+                len(_GATHERED.findall(layouts)))
+    assert per_mode == {m: [operands] * calls for m in range(len(dims))}
+    for shape_text in re.findall(r"= f32\[([\d,]+)\]\S* multiply\(", text):
+        lead = [int(d) for d in shape_text.split(",")[:-1]]
+        assert math.prod(lead) <= max(dims), shape_text
 
 
 def test_kernel_keeps_its_name_under_its_scope(one_chip):
