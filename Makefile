@@ -15,9 +15,6 @@ bench:
 bench-quick:
 	PYTHONPATH=$(PYTHONPATH) $(PY) -m benchmarks.run --quick
 
-bench-compress:
-	PYTHONPATH=$(PYTHONPATH) $(PY) -m benchmarks.bench_compress
-
 bench-plan:
 	PYTHONPATH=$(PYTHONPATH) $(PY) -m benchmarks.bench_plan
 
@@ -57,7 +54,7 @@ anchor:
 # (syntax + tabs/indentation errors) and import the package graph.
 lint:
 	$(PY) -m compileall -q src tests benchmarks examples
-	PYTHONPATH=$(PYTHONPATH) $(PY) -c "import repro.api, repro.api.cli, repro.core, repro.dist, repro.ingest, repro.plan, repro.serve, repro.methods, repro.kernels, repro.launch.mesh, repro.launch.steps, repro.models, repro.obs, repro.obs.report, repro.obs.exposition, repro.obs.recorder, repro.obs.aggregate, repro.optim, repro.checkpoint, repro.data, repro.utils.roofline, repro.configs"
+	PYTHONPATH=$(PYTHONPATH) $(PY) -c "import repro.api, repro.api.cli, repro.core, repro.dist, repro.ingest, repro.plan, repro.serve, repro.methods, repro.kernels, repro.launch.mesh, repro.launch.steps, repro.obs, repro.obs.report, repro.obs.exposition, repro.obs.recorder, repro.obs.aggregate, repro.checkpoint, repro.utils.roofline"
 
 quickstart:
 	PYTHONPATH=$(PYTHONPATH) $(PY) examples/quickstart.py

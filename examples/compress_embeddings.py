@@ -1,5 +1,4 @@
-"""The one genuine contact point between the paper's technique and the LM
-substrate: CP-compress an embedding table.
+"""CP-compress an embedding table with the paper's sparse CP-ALS.
 
 A (V, D) embedding reshaped to a 3rd-order tensor (V1, V2, D) admits a CP
 decomposition whose factors store V1*R + V2*R + D*R floats instead of V*D —

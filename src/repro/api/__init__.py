@@ -11,11 +11,9 @@
     cli.py       python -m repro {ingest,plan,fit,serve,dryrun} and the
                  --list-methods / --list-impls capability matrices
 
-Everything else under ``repro.*`` is either machinery this API drives
-(core/plan/ingest/methods/dist/checkpoint) or legacy seed modules kept for
-back-compat (``repro.models``, ``repro.optim``, the LM arch presets in
-``repro.configs`` — see docs/architecture.md "Legacy LM substrate"); new
-callers should enter through this package.
+Everything else under ``repro.*`` is machinery this API drives
+(core/plan/ingest/methods/dist/checkpoint/serve/obs) or the launchers over
+it (``repro.launch``); new callers should enter through this package.
 """
 from .config import (ConfigError, DataConfig, ExecConfig, MethodConfig,
                      ObsConfig, PlanConfig, RunConfig, ServeConfig)

@@ -29,6 +29,8 @@ import sys
 import time
 from typing import Optional, Sequence
 
+from repro.core.coo import PAPER_WORKLOADS
+
 from .config import ConfigError, RunConfig
 
 
@@ -530,8 +532,8 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
 
     p = sub.add_parser("dryrun",
                        help="compile-matrix dry-run (repro.launch.dryrun)")
-    p.add_argument("--workload", required=True,
-                   help="cpals-<workload> or an arch id")
+    p.add_argument("--workload", required=True, choices=tuple(PAPER_WORKLOADS),
+                   help="the paper workload to dry-run")
     p.add_argument("--mesh", choices=["single", "multi"], default="single")
     p.add_argument("--tag", default="")
     p.add_argument("--override", action="append", default=[])

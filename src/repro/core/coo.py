@@ -181,6 +181,17 @@ PAPER_DATASETS: dict[str, tuple[tuple[int, ...], int, float]] = {
     "netflix": ((480_000, 18_000, 2_000), 100_000_000, 0.5),
 }
 
+# The paper's CP rank for every data set.
+PAPER_RANK = 35
+
+# Launcher workload ids (``--arch`` of ``repro.launch.dryrun``/``serve``,
+# ``repro dryrun --workload``, dry-run artifact cell ids) -> PAPER_DATASETS key.
+PAPER_WORKLOADS: dict[str, str] = {
+    "cpals-yelp": "yelp",
+    "cpals-nell2": "nell-2",
+    "cpals-netflix": "netflix",
+}
+
 
 def paper_dataset(name: str, key: Array, *, scale: float = 1.0) -> SparseTensor:
     """Synthetic tensor with the published shape/density of a paper data set.

@@ -17,9 +17,9 @@ Multi-pod: the 'pod' axis joins 'data' as the mode-0 row axis, so the same
 spec expresses reduce within the pod + all-reduce across pods over DCN.
 
 Axis resolution and the psum/reduce-scatter phrasing live in
-``repro.dist.collectives`` (shared with the LM path's ``launch/mesh.py``);
-this module only contains what is CP-ALS specific: the host-side non-zero
-partitioner and the shard_map iteration body.  See ``docs/architecture.md``.
+``repro.dist.collectives`` (shared with ``launch/mesh.py``); this module
+only contains what is CP-ALS specific: the host-side non-zero partitioner
+and the shard_map iteration body.  See ``docs/architecture.md``.
 """
 from __future__ import annotations
 
@@ -37,7 +37,7 @@ from repro.obs import trace as obs_trace
 from repro.dist.collectives import (cpals_axes, gather_rows, pgram,
                                     pnormalize_columns, scatter_rows)
 
-from .coo import SparseTensor
+from .coo import PAPER_DATASETS, PAPER_RANK, PAPER_WORKLOADS, SparseTensor
 from .gram import (column_norms, gram, hadamard_grams, kruskal_fit,
                    solve_cholesky, normalize)
 
@@ -407,9 +407,8 @@ def build_dist_cpals_lowered(workload: str, mesh: Mesh, *,
                              local_impls: tuple[str, str, str] = ("scatter",) * 3):
     """Abstract (ShapeDtypeStruct) lowering of one distributed ALS iteration
     for a paper workload — the CP-ALS entry of the dry-run matrix."""
-    from repro.configs import CPALS_WORKLOADS
-
-    dims, nnz, rank = CPALS_WORKLOADS[workload]
+    dims, nnz, _ = PAPER_DATASETS[PAPER_WORKLOADS[workload]]
+    rank = PAPER_RANK
     if mode_order == "auto":
         dims = tuple(sorted(dims, reverse=True))
     ax = cpals_axes(mesh)

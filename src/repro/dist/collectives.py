@@ -1,17 +1,15 @@
 """Shared collectives vocabulary: mesh-axis resolution + psum plumbing.
 
-Before this module existed, ``repro.core.distributed`` (medium-grained
-CP-ALS) and ``repro.launch.mesh`` (LM sharding rules) each re-derived the
-same facts about the production mesh: which axes partition rows vs
-columns, how the pod axis joins the batch/row partition, and how a
-column-normalize or Gram reduce is phrased inside ``shard_map``.  This
-module is the single home for that vocabulary so both paths agree by
-construction.
+``repro.core.distributed`` (medium-grained CP-ALS) and ``repro.launch.mesh``
+(the dry-run's production mesh) both need the same facts about a mesh:
+which axes partition rows vs columns, how the pod axis joins the row
+partition, and how a column-normalize or Gram reduce is phrased inside
+``shard_map``.  This module is the single home for that vocabulary so both
+agree by construction.
 
 Conventions (see ``launch/mesh.py`` for the physical shapes):
 
-  * ``"model"`` is always the *column* axis of the CP-ALS grid and the
-    tensor-parallel axis of the LM path;
+  * ``"model"`` is always the *column* axis of the CP-ALS grid;
   * every other axis — ``("data",)`` single-pod, ``("pod", "data")``
     multi-pod — is a *row* axis.  The pod axis joining the row partition
     is what makes one reduce spec express "psum within the pod over ICI
@@ -20,8 +18,8 @@ Conventions (see ``launch/mesh.py`` for the physical shapes):
 The reduce helpers (:func:`pnormalize_columns`, :func:`pgram`,
 :func:`scatter_rows`, :func:`gather_rows`) are for use *inside*
 ``shard_map`` bodies; the resolution helpers (:func:`cpals_axes`,
-:func:`batch_axes`, :func:`axis_product`) are host-side and touch no jax
-device state.  See ``docs/architecture.md`` ("The distributed layer").
+:func:`axis_product`) are host-side and touch no jax device state.  See
+``docs/architecture.md`` ("The distributed layer").
 """
 from __future__ import annotations
 
@@ -61,12 +59,6 @@ def axis_product(mesh: Mesh, axes: Sequence[str]) -> int:
     """Number of devices along ``axes`` (product of mesh extents)."""
     return int(np.prod([mesh.shape[a] for a in axes], dtype=np.int64)) \
         if axes else 1
-
-
-def batch_axes(multi_pod: bool = False) -> AxisName:
-    """The pod-aware batch/data-parallel rule: across pods the batch is
-    purely data-parallel, so the pod axis prepends the data axis."""
-    return (POD_AXIS, DATA_AXIS) if multi_pod else DATA_AXIS
 
 
 @dataclasses.dataclass(frozen=True)

@@ -1,3 +1,3 @@
-from .mesh import make_production_mesh, rules_for, sharding_fn
+from .mesh import make_production_mesh
 
-__all__ = ["make_production_mesh", "rules_for", "sharding_fn"]
+__all__ = ["make_production_mesh"]

@@ -1,24 +1,19 @@
-"""Production mesh + logical-axis sharding rules (MaxText-style).
+"""The production mesh of the distributed CP-ALS dry-run.
 
 ``make_production_mesh`` is a FUNCTION (not a module constant) so importing
 this module never touches jax device state.  The dry-run target is
   single-pod:  (data=16, model=16)          = 256 chips (TPU v5e pod)
   multi-pod:   (pod=2, data=16, model=16)   = 512 chips
 
-Axis *names* and pod-aware batch rules come from
-``repro.dist.collectives`` — the same vocabulary the distributed CP-ALS
-path resolves its row/column grid from — so the LM and tensor-
-decomposition paths cannot drift apart.  See ``docs/architecture.md``.
+Axis *names* come from ``repro.dist.collectives`` — the same vocabulary the
+distributed CP-ALS path resolves its row/column grid from.  See
+``docs/architecture.md``.
 """
 from __future__ import annotations
 
-from typing import Optional
+from jax.sharding import Mesh
 
-import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from repro.dist.collectives import (DATA_AXIS, MODEL_AXIS, POD_AXIS,
-                                    axis_product, batch_axes, make_mesh)
+from repro.dist.collectives import DATA_AXIS, MODEL_AXIS, POD_AXIS, make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -26,100 +21,3 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     axes = ((POD_AXIS, DATA_AXIS, MODEL_AXIS) if multi_pod
             else (DATA_AXIS, MODEL_AXIS))
     return make_mesh(shape, axes)
-
-
-# ---------------------------------------------------------------------------
-# logical axis -> mesh axis rules
-# ---------------------------------------------------------------------------
-
-# baseline rules; `embed` flips to the FSDP axis for cfg.fsdp archs
-BASE_RULES: dict[str, Optional[str]] = {
-    "vocab": "model",
-    "heads": "model",
-    "kv_heads": "model",
-    "head_dim": None,
-    "mlp": "model",
-    "experts": "model",
-    "experts_r": None,
-    "embed": None,
-    "embed_out": "model",
-    "rnn": "model",
-    "rnn_out": None,
-    "layers": None,
-    "norm": None,
-    "conv": None,
-    "lora": None,
-    "five": None,
-    # caches / activations
-    "cache_batch": "data",
-    "cache_seq": None,
-    "act_batch": "data",
-    # context-parallel flash attention: shard q blocks over 'model' for
-    # archs whose head count does not divide the mesh (yi/llama3.2/qwen2-vl)
-    "flash_q": None,
-}
-
-
-def rules_for(cfg=None, *, multi_pod: bool = False,
-              overrides: dict | None = None) -> dict:
-    rules = dict(BASE_RULES)
-    if cfg is not None and getattr(cfg, "fsdp", False):
-        rules["embed"] = "data"
-    if multi_pod:
-        # batch dims extend over the pod axis (pure DP across pods) —
-        # the same pod-aware rule the CP-ALS row partition uses
-        rules["cache_batch"] = batch_axes(multi_pod=True)
-        rules["act_batch"] = batch_axes(multi_pod=True)
-    if overrides:
-        rules.update(overrides)
-    return rules
-
-
-def spec_for(axes: tuple, shape: tuple, mesh: Mesh, rules: dict, *,
-             allow_uneven: bool = False) -> P:
-    """PartitionSpec for a leaf with logical ``axes``.
-
-    A dim is sharded only when divisible by the mesh axis (JAX rejects
-    uneven input shardings).  Non-divisible head counts are handled by the
-    context-parallel flash path instead (rules override ``flash_q``)."""
-    parts = []
-    used = set()
-    for dim, ax in zip(shape, axes):
-        rule = rules.get(ax) if ax is not None else None
-        if rule is None:
-            parts.append(None)
-            continue
-        mesh_axes = rule if isinstance(rule, tuple) else (rule,)
-        size = axis_product(mesh, mesh_axes)
-        ok = (dim % size == 0) or (allow_uneven and dim >= size)
-        if ok and not (set(mesh_axes) & used):
-            parts.append(rule)
-            used.update(mesh_axes)
-        else:
-            parts.append(None)
-    while parts and parts[-1] is None:
-        parts.pop()
-    return P(*parts)
-
-
-def sharding_fn(mesh: Mesh, rules: dict):
-    def f(axes: tuple, shape: tuple) -> NamedSharding:
-        return NamedSharding(mesh, spec_for(axes, shape, mesh, rules))
-    return f
-
-
-def batch_sharding(mesh: Mesh, rules: dict, kind: str, shape: tuple) -> NamedSharding:
-    """Sharding for an input-batch leaf: batch dim -> act_batch rule."""
-    brule = rules.get("act_batch", DATA_AXIS)
-    baxes = brule if isinstance(brule, tuple) else (brule,)
-    size = axis_product(mesh, baxes)
-    if kind == "positions":       # (3, B, S)
-        b = shape[1]
-        spec = P(None, brule, None) if b % size == 0 else P()
-    elif kind in ("tokens",):     # (B, S)
-        spec = P(brule, None) if shape[0] % size == 0 else P()
-    elif kind == "act":           # (B, S, D)
-        spec = P(brule, None, None) if shape[0] % size == 0 else P()
-    else:
-        spec = P()
-    return NamedSharding(mesh, spec)

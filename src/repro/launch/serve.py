@@ -1,21 +1,14 @@
 """Serving launcher: the decomposition-serving path for the paper's own
 CP-ALS workloads (plan-driven decompose, then batched reconstruction
-queries), plus the **Legacy LM substrate**'s token-serving loop (batched
-prefill + decode with the per-arch KV/state caches — kept for back-compat
-with the seed's LM archs, like ``repro.models``/``repro.optim``; see
-docs/architecture.md "Legacy LM substrate").
+queries).
 
   PYTHONPATH=src python -m repro.launch.serve --arch cpals-yelp --smoke \
       --batch 256 --queries 2048
-  PYTHONPATH=src python -m repro.launch.serve --arch rwkv6-3b --smoke \
-      --batch 4 --prompt-len 32 --gen 16        # legacy LM path
 
-The decomposition path is the supported one — it drives
-:class:`repro.api.Session`, shares its RunConfig with ``python -m repro
-serve``, and the production serving layer on top of it is
+It drives :class:`repro.api.Session`, shares its RunConfig with ``python -m
+repro serve``, and the production serving layer on top of it is
 ``repro.serve`` (``python -m repro serve-daemon``).  CPU-sized with
---smoke; the production shapes are proven by the dry-run's serve_step /
-cpals cells.
+--smoke; the production shapes are proven by the dry-run's cpals cells.
 """
 from __future__ import annotations
 
@@ -23,69 +16,8 @@ import argparse
 import time
 
 import jax
-import jax.numpy as jnp
-import numpy as np
 
-from repro import configs
-from repro.configs import CPALS_DATASET
-from repro.launch.steps import make_prefill_step, make_serve_step
-from repro.models import Model
-
-
-def serve(arch: str, *, smoke: bool, batch: int, prompt_len: int, gen: int,
-          seed: int = 0, greedy: bool = True) -> dict:
-    """**Legacy LM substrate**: token serving (prefill + decode) for the
-    seed's LM archs.  Not the decomposition path — that is
-    :func:`serve_cpd` here and ``repro.serve`` in production."""
-    cfg = configs.get(arch)
-    if smoke:
-        cfg = configs.smoke_of(cfg)
-    model = Model(cfg)
-    params = model.init(jax.random.PRNGKey(seed))
-
-    rng = np.random.default_rng(seed)
-    prompts = jnp.asarray(rng.integers(0, cfg.vocab, (batch, prompt_len),
-                                       dtype=np.int32))
-    pre_batch = {}
-    if cfg.input_mode == "embeds":
-        from repro.models import layers as L
-        pre_batch["embeds"] = L.embed({"table": params["embed"]["table"]},
-                                      cfg, prompts)
-    else:
-        pre_batch["tokens"] = prompts
-    if cfg.rope == "mrope":
-        pre_batch["positions"] = jnp.broadcast_to(
-            jnp.arange(prompt_len)[None, None],
-            (3, batch, prompt_len)).astype(jnp.int32)
-    if cfg.encdec:
-        pre_batch["src_embeds"] = jnp.asarray(
-            rng.standard_normal((batch, 16, cfg.d_model), dtype=np.float32))
-
-    cache = model.init_cache(batch, prompt_len + gen,
-                             src_len=16 if cfg.encdec else 0)
-    prefill = jax.jit(make_prefill_step(model))
-    step = jax.jit(make_serve_step(model), donate_argnums=(2,))
-
-    t0 = time.time()
-    logits, cache = prefill(params, pre_batch, cache)
-    t_prefill = time.time() - t0
-
-    toks = [jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)]
-    t0 = time.time()
-    for i in range(gen - 1):
-        pos = jnp.array(prompt_len + i, dtype=jnp.int32)
-        positions = None
-        if cfg.rope == "mrope":
-            positions = jnp.full((3, batch, 1), prompt_len + i, jnp.int32)
-        logits, cache = step(params, toks[-1][:, None], cache, pos, positions)
-        toks.append(jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32))
-    jax.block_until_ready(toks[-1])
-    t_decode = time.time() - t0
-
-    out = jnp.stack(toks, axis=1)
-    return {"tokens": np.asarray(out), "prefill_s": t_prefill,
-            "decode_s": t_decode,
-            "decode_tok_s": batch * (gen - 1) / max(t_decode, 1e-9)}
+from repro.core.coo import PAPER_WORKLOADS
 
 
 def cpd_config(workload: str, *, smoke: bool, rank: int, niters: int,
@@ -99,7 +31,7 @@ def cpd_config(workload: str, *, smoke: bool, rank: int, niters: int,
     # the one capability gate (raises with the registry listing if unknown)
     spec = require_capability(method, "local")
     return RunConfig(
-        data=DataConfig(dataset=CPALS_DATASET[workload],
+        data=DataConfig(dataset=PAPER_WORKLOADS[workload],
                         scale=0.002 if smoke else 1.0, seed=seed,
                         reorder=reorder, cache=cache),
         plan=PlanConfig(policy=policy),
@@ -163,52 +95,39 @@ def serve_cpd(workload: str, *, smoke: bool, batch: int, queries: int,
 
 def main() -> None:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", required=True,
-                    choices=tuple(configs.ARCH_NAMES) + tuple(CPALS_DATASET),
-                    help="cpals-<workload> = decomposition serving (the "
-                         "supported path); LM arch names = Legacy LM "
-                         "substrate token serving")
+    ap.add_argument("--arch", required=True, choices=tuple(PAPER_WORKLOADS),
+                    help="the paper workload to decompose and serve")
     ap.add_argument("--smoke", action="store_true")
     ap.add_argument("--batch", type=int, default=4)
-    ap.add_argument("--prompt-len", type=int, default=32)
-    ap.add_argument("--gen", type=int, default=16)
     ap.add_argument("--queries", type=int, default=2048,
-                    help="cpals serving: total reconstruction queries")
+                    help="total reconstruction queries")
     ap.add_argument("--rank", type=int, default=16)
     ap.add_argument("--iters", type=int, default=10)
     ap.add_argument("--impl", default="auto",
-                    help="cpals serving: planner policy (auto or impl name)")
+                    help="planner policy (auto or impl name)")
     ap.add_argument("--method", default="cp_als",
-                    help="cpals serving: decomposition method "
-                    "(repro.methods registry: cp_als/cp_nn_hals/"
-                    "tucker_hooi/cp_als_streaming)")
+                    help="decomposition method (repro.methods registry: "
+                    "cp_als/cp_nn_hals/tucker_hooi/cp_als_streaming)")
     ap.add_argument("--reorder", default="identity",
-                    help="cpals serving: ingest reordering "
+                    help="ingest reordering "
                     "(identity/degree_sort/random_block)")
     ap.add_argument("--cache", default=None,
-                    help="cpals serving: ingest cache root (warm relaunch "
-                    "skips sort+stats)")
+                    help="ingest cache root (warm relaunch skips "
+                    "sort+stats)")
     args = ap.parse_args()
-    if args.arch in CPALS_DATASET:
-        out = serve_cpd(args.arch, smoke=args.smoke,
-                        batch=args.batch, queries=args.queries,
-                        rank=args.rank, niters=args.iters, policy=args.impl,
-                        reorder=args.reorder, cache=args.cache,
-                        method=args.method)
-        print(f"[serve] method {out['method']}  plan {out['plan']}  "
-              f"fit {out['fit']:.4f}  "
-              f"ingest {out['ingest_s']:.2f}s"
-              f"{' (cache hit)' if out['cache_hit'] else ''}  "
-              f"decompose {out['decompose_s']:.2f}s  "
-              f"serve {out['serve_s']:.2f}s ({out['qps']:,.0f} vals/s, "
-              f"p50 {out['latency_ms']['p50']:.2f}ms "
-              f"p99 {out['latency_ms']['p99']:.2f}ms)")
-        return
-    out = serve(args.arch, smoke=args.smoke, batch=args.batch,
-                prompt_len=args.prompt_len, gen=args.gen)
-    print(f"[serve] prefill {out['prefill_s']:.2f}s  decode "
-          f"{out['decode_s']:.2f}s  ({out['decode_tok_s']:,.0f} tok/s)")
-    print(f"[serve] sample tokens: {out['tokens'][0][:12].tolist()}")
+    out = serve_cpd(args.arch, smoke=args.smoke,
+                    batch=args.batch, queries=args.queries,
+                    rank=args.rank, niters=args.iters, policy=args.impl,
+                    reorder=args.reorder, cache=args.cache,
+                    method=args.method)
+    print(f"[serve] method {out['method']}  plan {out['plan']}  "
+          f"fit {out['fit']:.4f}  "
+          f"ingest {out['ingest_s']:.2f}s"
+          f"{' (cache hit)' if out['cache_hit'] else ''}  "
+          f"decompose {out['decompose_s']:.2f}s  "
+          f"serve {out['serve_s']:.2f}s ({out['qps']:,.0f} vals/s, "
+          f"p50 {out['latency_ms']['p50']:.2f}ms "
+          f"p99 {out['latency_ms']['p99']:.2f}ms)")
 
 
 if __name__ == "__main__":

@@ -1,92 +1,8 @@
-"""Step builders: train_step / prefill_step / serve_step for any arch config,
-plus the CP-ALS iteration step for decomposition workloads.
-
-These are the functions the dry-run lowers and the real launcher executes.
-Gradient compression (the ``grad_compress`` flag) is implemented by
-``repro.dist.compress`` — int8 quantization with error-feedback residuals;
-the CP-ALS step executes a per-mode :class:`repro.plan.DecompPlan`; see
+"""Step builder for the CP-ALS iteration of a decomposition workload: it
+executes a per-mode :class:`repro.plan.DecompPlan`; see
 ``docs/architecture.md``.
 """
 from __future__ import annotations
-
-from functools import partial
-from typing import Any, Optional
-
-import jax
-import jax.numpy as jnp
-
-from repro.models import Model
-from repro.models.config import ModelConfig
-from repro.optim import Optimizer
-from repro.dist.compress import compress_grads_int8, decompress_grads_int8
-
-Array = jax.Array
-
-
-def _split_micro(batch: dict, m: int) -> dict:
-    """Reshape every batch leaf to a leading microbatch axis of length m."""
-    out = {}
-    for k, v in batch.items():
-        if k == "positions":  # (3, B, S) -> (m, 3, B/m, S)
-            b = v.shape[1]
-            out[k] = jnp.moveaxis(
-                v.reshape(v.shape[0], m, b // m, *v.shape[2:]), 1, 0)
-        else:                 # (B, ...) -> (m, B/m, ...)
-            out[k] = v.reshape(m, v.shape[0] // m, *v.shape[1:])
-    return out
-
-
-def make_train_step(model: Model, optimizer: Optimizer,
-                    *, grad_compress: bool = False, micro_batches: int = 1):
-    """(params, opt_state, batch, step) -> (params, opt_state, metrics).
-
-    ``grad_compress`` applies int8 quantization with error feedback to the
-    gradients, carrying the quantization residual in opt_state['ef'].  In
-    this jit path XLA inserts the data-parallel all-reduce implicitly, so
-    the flag exercises the full quantize->dequantize fidelity loop (what
-    convergence depends on) but the reduce itself still moves f32; wiring
-    the int8 payload through the collective needs an explicit shard_map'd
-    psum of (q, scales) and is the planned follow-up (docs/architecture.md).
-
-    ``micro_batches`` > 1 accumulates gradients over batch splits (same
-    optimizer math, ~1/m peak activation memory — what lets the big train
-    cells fit a 16 GiB v5e)."""
-
-    def grads_of(params, batch):
-        return jax.value_and_grad(model.loss, has_aux=True)(params, batch)
-
-    def train_step(params, opt_state, batch, step):
-        if micro_batches > 1:
-            micro = _split_micro(batch, micro_batches)
-
-            def mb(carry, mbatch):
-                (loss_m, metrics), grads = grads_of(params, mbatch)
-                carry = jax.tree.map(
-                    lambda a, g: a + g.astype(jnp.float32) / micro_batches,
-                    carry, grads)
-                return carry, metrics
-
-            zero = jax.tree.map(
-                lambda p: jnp.zeros(p.shape, jnp.float32), params)
-            grads, metrics_all = jax.lax.scan(
-                mb, zero, micro,
-                unroll=True if model.cfg.unroll_loops else 1)
-            metrics = {k: jnp.mean(v) for k, v in metrics_all.items()}
-        else:
-            (loss, metrics), grads = grads_of(params, batch)
-        if grad_compress:
-            ef = opt_state.get("ef")
-            q, scales, ef = compress_grads_int8(grads, ef)
-            grads = decompress_grads_int8(q, scales)
-        new_params, new_opt = optimizer.update(
-            grads, {k: v for k, v in opt_state.items() if k != "ef"},
-            params, step)
-        if grad_compress:
-            new_opt = dict(new_opt, ef=ef)
-        metrics = dict(metrics, step=step + 1)
-        return new_params, new_opt, metrics
-
-    return train_step
 
 
 def make_cpals_step(plan):
@@ -105,20 +21,3 @@ def make_cpals_step(plan):
                           impls=impls, norm_kind=norm_kind)
 
     return cpals_step
-
-
-def make_prefill_step(model: Model):
-    def prefill_step(params, batch, cache):
-        logits, new_cache = model.prefill(params, batch, cache)
-        return logits, new_cache
-    return prefill_step
-
-
-def make_serve_step(model: Model):
-    """One decode step: (params, tokens (B,1), cache, pos) -> (logits, cache)."""
-    cfg = model.cfg
-
-    def serve_step(params, tokens, cache, pos, positions=None):
-        return model.decode_step(params, tokens, cache, pos,
-                                 positions=positions)
-    return serve_step

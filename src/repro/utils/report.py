@@ -112,9 +112,6 @@ def dryrun_table(cells: list[dict]) -> str:
     rows = ["| cell | mesh | compile | peak/dev | args/dev | collective mix |",
             "|---|---|---|---|---|---|"]
     for c in cells:
-        if "skipped" in c:
-            rows.append(f"| {c['cell']} | — | SKIP | — | — | {c['skipped']} |")
-            continue
         mesh = "x".join(str(v) for v in c["mesh"].values())
         colls = c["roofline"]["collectives"]
         mix = " ".join(f"{k.split('-')[-1]}:{int(v['count'])}"
@@ -131,15 +128,13 @@ def roofline_table(cells: list[dict], *, single_only: bool = True) -> str:
             "| MODEL_FLOPS/HLO | note |",
             "|---|---|---|---|---|---|---|---|"]
     for c in cells:
-        if "skipped" in c:
-            continue
         if single_only and "__multi" in c["cell"]:
             continue
         r = c["roofline"]
         useful = r["useful_ratio"]
         note = ""
         if useful > 1.0:
-            note = "HLO<6ND (sparse/active<total)"
+            note = "HLO<model flops"
         rows.append(
             f"| {c['cell'].replace('__single','')} | {_fmt_s(r['compute_s'])} "
             f"| {_fmt_s(r['memory_s'])} | {_fmt_s(r['collective_s'])} "
@@ -148,32 +143,18 @@ def roofline_table(cells: list[dict], *, single_only: bool = True) -> str:
     return "\n".join(rows)
 
 
-def pick_hillclimb(cells: list[dict]) -> list[str]:
-    """worst useful ratio, most collective-bound, paper-representative."""
-    live = [c for c in cells if "skipped" not in c and "__single" in c["cell"]
-            and not c["cell"].startswith("cpals")]
-    worst = min(live, key=lambda c: min(1.0, c["roofline"]["useful_ratio"])
-                / max(c["roofline"]["bound_s"], 1e-9)
-                * c["roofline"]["compute_s"])
-    coll = max(live, key=lambda c: c["roofline"]["collective_s"]
-               / max(c["roofline"]["bound_s"], 1e-9))
-    return [worst["cell"], coll["cell"], "cpals-nell2__iteration__single"]
-
-
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--dir", type=Path,
                     default=Path("artifacts/dryrun"))
-    ap.add_argument("--section", choices=["dryrun", "roofline", "pick"],
+    ap.add_argument("--section", choices=["dryrun", "roofline"],
                     default="roofline")
     args = ap.parse_args()
     cells = load_cells(args.dir)
     if args.section == "dryrun":
         print(dryrun_table(cells))
-    elif args.section == "roofline":
-        print(roofline_table(cells))
     else:
-        print(pick_hillclimb(cells))
+        print(roofline_table(cells))
 
 
 if __name__ == "__main__":

@@ -149,7 +149,7 @@ class Roofline:
     memory_s: float
     collective_s: float
     dominant: str
-    model_flops: float           # global 6ND (or 2ND serve)
+    model_flops: float           # useful flops the caller counts
     useful_ratio: float          # model_flops / (flops * chips)
     collectives: dict
     bound_s: float
@@ -189,17 +189,3 @@ def analyze(cost: dict, hlo: str, *, n_chips: int, model_flops: float,
         wire_bytes=sum(c["wire"] for c in colls),
         collectives=collective_summary(colls),
         n_chips=n_chips, model_flops=model_flops, kind=kind)
-
-
-def model_flops_estimate(cfg, shape) -> float:
-    """MODEL_FLOPS: 6*N_active*D for training, 2*N_active*D for serving
-    (D = tokens processed by the step)."""
-    n = cfg.active_param_count()
-    if shape.kind == "train":
-        tokens = shape.global_batch * shape.seq_len
-        return 6.0 * n * tokens
-    if shape.kind == "prefill":
-        tokens = shape.global_batch * shape.seq_len
-        return 2.0 * n * tokens
-    tokens = shape.global_batch  # decode: one token per sequence
-    return 2.0 * n * tokens

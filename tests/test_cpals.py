@@ -24,26 +24,63 @@ def small_tensor(order=3, skew=0.0, nnz=500, key=KEY):
 # MTTKRP variants vs the dense oracle
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("impl", ["gather_scatter", "segment", "rowloop"])
+# the impls that read the sorted CSF workspace; the others take the COO tensor
+CSF_IMPLS = ("segment", "pallas")
+
+
+@pytest.mark.parametrize("impl", ["gather_scatter", "segment", "rowloop",
+                                  "pallas"])
 @pytest.mark.parametrize("mode", [0, 1, 2])
 @pytest.mark.parametrize("skew", [0.0, 1.5])
 def test_mttkrp_matches_dense(impl, mode, skew):
     t = small_tensor(skew=skew)
     factors = init_factors(t.dims, 8, KEY)
     want = mttkrp(t, factors, mode, impl="dense")
-    x = build_csf(t, mode, block=64) if impl == "segment" else t
+    x = build_csf(t, mode, block=64) if impl in CSF_IMPLS else t
     got = mttkrp(x, factors, mode, impl=impl)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-4)
 
 
-@pytest.mark.parametrize("mode", [0, 1, 2, 3])
-def test_mttkrp_order4(mode):
-    """The paper limits itself to 3rd order; arbitrary order is our extension."""
-    t = small_tensor(order=4, nnz=300)
+# tensors of order 4 and 5 small enough for the dense oracle (dims <= 12)
+HIGH_ORDER_DIMS = {4: (12, 9, 11, 7), 5: (12, 9, 11, 7, 10)}
+
+
+@pytest.mark.parametrize("impl", ["gather_scatter", "segment", "rowloop",
+                                  "pallas"])
+@pytest.mark.parametrize("order, mode",
+                         [(o, m) for o in (4, 5) for m in range(o)])
+def test_mttkrp_high_order_matches_dense(order, mode, impl):
+    """The paper limits itself to 3rd order; every impl takes orders 4 and 5
+    (the pallas kernel forms the whole Khatri-Rao product of 3 or 4
+    gathered operands) and matches the dense oracle on skewed keys."""
+    t = random_sparse(HIGH_ORDER_DIMS[order], 400, KEY, skew=1.5)
     factors = init_factors(t.dims, 5, KEY)
     want = mttkrp(t, factors, mode, impl="dense")
-    got = mttkrp(build_csf(t, mode, block=64), factors, mode, impl="segment")
+    x = build_csf(t, mode, block=64) if impl in CSF_IMPLS else t
+    got = mttkrp(x, factors, mode, impl=impl)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("order, mode",
+                         [(o, m) for o in (4, 5) for m in range(o)])
+def test_build_csf_keeps_each_nonzero_once(order, mode):
+    """The mode-sorted workspace of an order-4 or -5 tensor holds every
+    non-zero exactly once (padding entries carry value 0), its rows in
+    non-decreasing order."""
+    t = random_sparse(HIGH_ORDER_DIMS[order], 400, KEY, skew=1.5)
+    csf = build_csf(t, mode, block=32)
+    rows = np.asarray(csf.row_ids)
+    vals = np.asarray(csf.vals)
+    assert np.all(np.diff(rows) >= 0)
+    real = vals != 0.0
+    coords = np.empty((int(real.sum()), order), np.int64)
+    coords[:, mode] = rows[real]
+    coords[:, [m for m in range(order) if m != mode]] = \
+        np.asarray(csf.other_ids)[real]
+    got = sorted(zip(map(tuple, coords.tolist()), vals[real].tolist()))
+    want = sorted(zip(map(tuple, np.asarray(t.inds[:t.nnz]).tolist()),
+                      np.asarray(t.vals[:t.nnz]).tolist()))
+    assert got == want
 
 
 def test_mttkrp_padding_is_noop():
@@ -96,7 +133,9 @@ def test_hadamard_grams_skips_mode():
     np.testing.assert_allclose(np.asarray(v), np.full((3, 3), 2.0 * 4.0))
 
 
-@pytest.mark.parametrize("rows", [7, 512, 513, 1500, 100_000])
+@pytest.mark.parametrize("rows", [1, 7, GRAM_ROWS - 1, 512, 513,
+                                  2 * GRAM_ROWS, 2 * GRAM_ROWS + 1, 1500,
+                                  4 * GRAM_ROWS, 100_000])
 def test_gram_matches_float64(rows):
     """A Gram within float32 round-off of float64 at any height (read 3.8e-8
     to 1.5e-7 here), the same from an eager call as from a jitted one: a

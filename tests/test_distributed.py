@@ -77,65 +77,17 @@ def test_dist_cpals_multipod_mesh():
     assert "MULTIPOD OK" in out
 
 
-def test_dryrun_mini_cell_and_roofline_parser():
-    """Reduced arch through the real dry-run path on a small mesh; the HLO
-    parser must find the data-parallel gradient all-reduce."""
-    out = run_py("""
-        import jax, jax.numpy as jnp, dataclasses
-        from repro.dist.collectives import make_mesh
-        from repro import configs
-        from repro.launch.mesh import rules_for, sharding_fn, batch_sharding
-        from repro.launch.steps import make_train_step
-        from repro.models import Model
-        from repro.models.config import ShapeConfig
-        from repro.models.params import axes_tree
-        from repro.optim import OPTIMIZERS
-        from repro.utils import roofline as RL
-        from repro.launch.dryrun import _map_axes, _sds
-
-        mesh = make_mesh((4, 2), ("data", "model"))
-        cfg = configs.smoke_of(configs.get("llama3.2-3b"))
-        cfg = dataclasses.replace(cfg, vocab=1024, d_model=128, d_ff=256,
-                                  num_heads=8, num_kv_heads=2)
-        shape = ShapeConfig("mini", 128, 8, "train")
-        rules = rules_for(cfg)
-        sfn = sharding_fn(mesh, rules)
-        model = Model(cfg)
-        params_abs = model.abstract(sfn)
-        bshapes = configs.batch_shapes(cfg, shape)
-        batch_abs = {k: _sds(sh, dt, batch_sharding(mesh, rules, kind, sh))
-                     for k, (sh, dt, kind) in bshapes.items()}
-        optimizer = OPTIMIZERS["adamw"]()
-        opt_shapes = jax.eval_shape(optimizer.init, params_abs)
-        opt_axes = optimizer.state_axes(axes_tree(model.param_specs()))
-        opt_abs = _map_axes(opt_shapes, opt_axes,
-                            lambda s, a: _sds(s.shape, s.dtype, sfn(a, s.shape)))
-        fn = make_train_step(model, optimizer)
-        lowered = jax.jit(fn, donate_argnums=(0, 1)).lower(
-            params_abs, opt_abs, batch_abs, _sds((), jnp.int32))
-        compiled = lowered.compile()
-        cost = compiled.cost_analysis()
-        hlo = compiled.as_text()
-        rl = RL.analyze(cost, hlo, n_chips=8, model_flops=6.0 * 1e6 * 1024,
-                        kind="TPU v5 lite")
-        print("flops", rl.flops, "colls", sorted(rl.collectives))
-        assert rl.flops > 0 and rl.bytes_accessed > 0
-        assert "all-reduce" in rl.collectives, rl.collectives
-        assert rl.collectives["all-reduce"]["wire"] > 0
-        mem = compiled.memory_analysis()
-        assert mem.temp_size_in_bytes > 0
-        print("MINI DRYRUN OK")
-    """)
-    assert "MINI DRYRUN OK" in out
-
-
 def test_dist_cpals_dryrun_lowering():
     """Abstract lowering of the distributed CP-ALS iteration on a small mesh
-    (same code path the production dry-run uses for cpals-* cells)."""
+    (same code path the production dry-run uses for cpals-* cells).  The
+    roofline's HLO parser reads the compiled text's all-reduces: each mode's
+    psum over its partial-row axes, so groups of 2 ('model'), 4 (the row
+    axes 'pod' x 'data') and 8 (the whole mesh)."""
     out = run_py("""
         import jax
         from repro.dist.collectives import make_mesh
         from repro.core.distributed import build_dist_cpals_lowered
+        from repro.utils import roofline as RL
         mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         lowered, info = build_dist_cpals_lowered("cpals-yelp", mesh)
         compiled = lowered.compile()
@@ -143,38 +95,13 @@ def test_dist_cpals_dryrun_lowering():
         assert cost["flops"] > 0
         hlo = compiled.as_text()
         assert "all-reduce" in hlo
+        groups = {c["group"] for c in RL.parse_collectives(hlo)
+                  if c["kind"] == "all-reduce" and c["wire"] > 0}
+        print("all-reduce groups", sorted(groups))
+        assert {2, 4, 8} <= groups, groups
         print("CPALS LOWER OK", info["local_cap"])
     """)
     assert "CPALS LOWER OK" in out
-
-
-def test_grad_compression_equivalence():
-    """int8+EF compressed training stays close to exact training."""
-    out = run_py("""
-        import jax, jax.numpy as jnp
-        from repro.dist.compress import (compress_grads_int8,
-                                         decompress_grads_int8,
-                                         init_error_feedback)
-        key = jax.random.PRNGKey(0)
-        w = jax.random.normal(key, (16, 4))
-        x = jax.random.normal(jax.random.fold_in(key, 1), (64, 16))
-        y = x @ jax.random.normal(jax.random.fold_in(key, 2), (16, 4))
-        def loss(w):
-            return jnp.mean((x @ w - y) ** 2)
-        w1 = w; w2 = w; ef = init_error_feedback({'w': w})
-        for i in range(60):
-            g1 = jax.grad(loss)(w1)
-            w1 = w1 - 0.01 * g1
-            g2 = jax.grad(loss)(w2)
-            q, s, ef = compress_grads_int8({'w': g2}, ef)
-            g2d = decompress_grads_int8(q, s)['w']
-            w2 = w2 - 0.01 * g2d
-        l1, l2 = float(loss(w1)), float(loss(w2))
-        print("exact", l1, "compressed", l2)
-        assert l2 < l1 * 1.5 + 1e-3
-        print("COMPRESS OK")
-    """, devices=1)
-    assert "COMPRESS OK" in out
 
 
 def test_dist_cpals_shard_c_and_mode_order_equivalent():
@@ -235,34 +162,3 @@ def test_dist_cpals_plan_interface():
         print("PLAN IFACE OK", plan.summary())
     """)
     assert "PLAN IFACE OK" in out
-
-
-def test_ep_moe_matches_dense_dispatch():
-    """Expert-parallel shard_map MoE == dense-dispatch oracle (fwd + grads)."""
-    out = run_py("""
-        import jax, jax.numpy as jnp
-        from repro.dist.collectives import make_mesh
-        from repro.models.config import ModelConfig, MoEConfig
-        from repro.models.moe import moe_ffn_ep, _moe_ffn_dense_dispatch, moe_specs
-        from repro.models.params import init_params
-        mesh = make_mesh((2, 4), ("data", "model"))
-        cfg = ModelConfig(name="m", family="moe", pattern=("moe",),
-                          num_layers=1, d_model=32, num_heads=2,
-                          num_kv_heads=2, head_dim=16, d_ff=64, vocab=64,
-                          moe=MoEConfig(num_experts=8, top_k=2, d_ff=32,
-                                        num_shared=1, capacity_factor=8.0),
-                          param_dtype="float32", compute_dtype="float32")
-        p = init_params(moe_specs(cfg), jax.random.PRNGKey(0), jnp.float32)
-        x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, 32)) * 0.5
-        ref, _ = _moe_ffn_dense_dispatch(p, cfg, x)
-        out, _ = jax.jit(lambda p, x: moe_ffn_ep(p, cfg, x, mesh))(p, x)
-        assert float(jnp.max(jnp.abs(out - ref))) < 1e-4
-        g1 = jax.jit(jax.grad(lambda p, x: jnp.sum(
-            moe_ffn_ep(p, cfg, x, mesh)[0] ** 2)))(p, x)
-        g2 = jax.grad(lambda p, x: jnp.sum(
-            _moe_ffn_dense_dispatch(p, cfg, x)[0] ** 2))(p, x)
-        for k in ("wg", "wd", "router", "shared_wg"):
-            assert float(jnp.max(jnp.abs(g1[k] - g2[k]))) < 1e-2, k
-        print("EP OK")
-    """)
-    assert "EP OK" in out

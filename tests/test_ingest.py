@@ -102,19 +102,30 @@ def test_read_tns_streams_in_chunks(tmp_path):
                                np.asarray(t.to_dense()), rtol=1e-6)
 
 
+def _assert_tns_roundtrip_bit_exact(t, path):
+    write_tns(path, t)
+    t2 = read_tns(path, dims=t.dims, duplicates="keep")
+    assert t2.nnz == t.nnz and t2.order == t.order
+    lin = lambda x: np.ravel_multi_index(
+        tuple(np.asarray(x.inds)[:, m] for m in range(t.order)), t.dims)
+    a = np.asarray(t.vals)[np.argsort(lin(t))]
+    b = np.asarray(t2.vals)[np.argsort(lin(t2))]
+    np.testing.assert_array_equal(np.sort(lin(t)), np.sort(lin(t2)))
+    np.testing.assert_array_equal(a, b)
+
+
 def test_write_read_tns_roundtrip_bit_exact(tmp_path):
     """The vectorized writer emits enough digits that every float32 value
     survives the text roundtrip bit-exactly."""
-    t = small_tensor(nnz=500)
-    p = tmp_path / "x.tns"
-    write_tns(p, t)
-    t2 = read_tns(p, dims=t.dims, duplicates="keep")
-    assert t2.nnz == t.nnz
-    lin = lambda x: np.ravel_multi_index(
-        tuple(np.asarray(x.inds)[:, m] for m in range(3)), t.dims)
-    a = np.asarray(t.vals)[np.argsort(lin(t))]
-    b = np.asarray(t2.vals)[np.argsort(lin(t2))]
-    np.testing.assert_array_equal(a, b)
+    _assert_tns_roundtrip_bit_exact(small_tensor(nnz=500), tmp_path / "x.tns")
+
+
+@pytest.mark.parametrize("dims", [(12, 9, 11, 7), (12, 9, 11, 7, 10)])
+def test_write_read_tns_roundtrip_bit_exact_high_order(tmp_path, dims):
+    """An order-4 or -5 tensor's lines carry one index per mode; its
+    coordinates and float32 values come back bit-exactly."""
+    _assert_tns_roundtrip_bit_exact(small_tensor(nnz=400, dims=dims),
+                                    tmp_path / "x.tns")
 
 
 # ---------------------------------------------------------------------------

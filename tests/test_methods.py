@@ -74,12 +74,24 @@ def test_fit_rejects_path_for_non_streaming_method():
 # acceptance: every method reconstructs a dense-reconstructible tensor
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("method", ALS_FAMILY)
-def test_methods_reach_fit_at_full_rank(lowrank, method):
-    """fit >= 0.99 at full rank on a fully-observed rank-4 tensor."""
-    _, ki = jax.random.split(KEY)
-    rank = (4, 4, 4) if method == "tucker_hooi" else 6
-    dec = fit(lowrank, rank, method=method, key=ki, **_fit_kwargs(method))
+# (dims, true rank): the order-3 tensor of the ``lowrank`` fixture, and
+# fully observed tensors of order 4 and 5 with at most 400 cells
+FULL_RANK_CASES = (
+    [pytest.param((12, 10, 8), 4, m, id=m) for m in ALS_FAMILY]
+    + [pytest.param(dims, 2, m, id=f"{m}-order{len(dims)}")
+       for dims in ((5, 4, 4, 5), (4, 3, 3, 3, 3)) for m in ALS_FAMILY])
+
+
+@pytest.mark.parametrize("dims, true_rank, method", FULL_RANK_CASES)
+def test_methods_reach_fit_at_full_rank(dims, true_rank, method):
+    """fit >= 0.99 on a fully-observed low-rank tensor of order 3, 4 or 5:
+    Tucker at the true multilinear rank, the CP methods at 1.5x the true
+    rank."""
+    kt, ki = jax.random.split(KEY)
+    t = exact_lowrank_tensor(dims, true_rank, kt)
+    rank = ((true_rank,) * len(dims) if method == "tucker_hooi"
+            else true_rank * 3 // 2)
+    dec = fit(t, rank, method=method, key=ki, **_fit_kwargs(method))
     assert float(dec.fit) >= 0.99, (method, float(dec.fit))
 
 

@@ -1,6 +1,6 @@
 """End-to-end system behaviour: the paper's pipeline from tensor to
-decomposition through the public API, the training/serving drivers, and the
-CP-ALS <-> LM contact point (factorized embeddings)."""
+decomposition through the public API, factorized embeddings, and orders
+beyond the paper's third."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -29,31 +29,9 @@ def test_paper_pipeline_end_to_end():
     assert timers["mttkrp"] > timers["fit"], timers
 
 
-def test_train_driver_learns():
-    from repro.launch.train import train
-    out = train("llama3.2-3b", smoke=True, steps=25, batch=8, seq=64,
-                ckpt_dir=None, lr=1e-3, log_every=100)
-    assert out["final_loss"] < out["first_loss"], out
-
-
-def test_serve_driver_all_cache_families():
-    from repro.launch.serve import serve
-    for arch in ("llama3.2-3b", "rwkv6-3b", "recurrentgemma-9b"):
-        out = serve(arch, smoke=True, batch=2, prompt_len=16, gen=4)
-        assert out["tokens"].shape == (2, 4)
-        assert np.all(out["tokens"] >= 0)
-
-
-def test_grad_compressed_training_converges():
-    from repro.launch.train import train
-    out = train("llama3.2-3b", smoke=True, steps=25, batch=8, seq=64,
-                ckpt_dir=None, lr=1e-3, grad_compress=True, log_every=100)
-    assert out["final_loss"] < out["first_loss"] + 0.05, out
-
-
 def test_factorized_embedding_contact_point():
-    """CP-ALS compresses a Kronecker-structured embedding (the one genuine
-    paper-technique <-> LM substrate integration)."""
+    """CP-ALS compresses a Kronecker-structured embedding table to a
+    quarter of its floats at fit > 0.95."""
     key = jax.random.PRNGKey(0)
     v1, v2, d, r = 16, 16, 32, 12
     a = jax.random.normal(jax.random.fold_in(key, 1), (v1, 6))
