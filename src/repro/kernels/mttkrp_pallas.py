@@ -22,6 +22,10 @@ mutexes; on a TPU we instead exploit the MXU:
     HBM.  The gathered factor rows it is formed from are: XLA gathers them,
     lane-padded, into one (nnz x 128) array per other mode that the kernel
     streams in;
+  * an operand may be gathered from a table that packs ``k`` factor rows to
+    a lane row (``ops._packed``): with it comes a stream of each row's slot,
+    and the kernel moves the slot's lanes to lanes ``[0, R)`` before the
+    product (``_unpacked``), an exact permutation of lanes;
   * the one-hot contraction is three single-pass bfloat16 matmuls, exact
     as ``Precision.HIGHEST`` is (``segment_sum``);
   * a call may carry the output in: given ``carry``, the output aliases it
@@ -104,10 +108,27 @@ def segment_sum(local: Array, prod: Array, row_tile: int) -> Array:
     return out
 
 
+def _unpacked(x: Array, slot: Array, width: int) -> Array:
+    """Each row of ``x`` (``(BLOCK, RP)`` float32, ``RP // width`` slots of
+    ``width`` lanes a row) with its slot ``slot`` (``(BLOCK, 1)``) moved to
+    lanes ``[0, width)``: one static lane roll for each slot past the
+    first, chosen row by row.  A permutation of lanes, so lanes
+    ``[0, width)`` are the slot's values to the bit; the lanes past them
+    hold the row's other slots."""
+    rp = x.shape[-1]
+    out = x
+    for s in range(1, rp // width):
+        out = jnp.where(slot == s, pltpu.roll(x, rp - s * width, 1), out)
+    return out
+
+
 def _kernel(tile_map_ref, rows_ref, vals_ref, *refs, row_tile: int,
-            num_operands: int, carried: bool):
+            packed: tuple[bool, ...], slot_width: Optional[int],
+            carried: bool):
+    num_operands = len(packed)
     operand_refs = refs[:num_operands]
-    carry_ref = refs[num_operands] if carried else None
+    slot_refs = iter(refs[num_operands:num_operands + sum(packed)])
+    carry_ref = refs[num_operands + sum(packed)] if carried else None
     out_ref = refs[-1]
     b = pl.program_id(0)
     tile = tile_map_ref[b]
@@ -121,14 +142,19 @@ def _kernel(tile_map_ref, rows_ref, vals_ref, *refs, row_tile: int,
         else:
             out_ref[...] = jnp.zeros_like(out_ref)
 
+    def operand(i: int) -> Array:
+        x = operand_refs[i][0].astype(jnp.float32)
+        if packed[i]:
+            x = _unpacked(x, next(slot_refs)[0, 0][:, None], slot_width)
+        return x
+
     # fused Khatri-Rao partial product, (BLOCK, R): (vals * op0) * (op1 *
     # op2 * ...), which at order 3 is (vals * brows) * crows
-    prod = (vals_ref[0, 0][:, None].astype(jnp.float32)
-            * operand_refs[0][0].astype(jnp.float32))
+    prod = vals_ref[0, 0][:, None].astype(jnp.float32) * operand(0)
     if num_operands > 1:
-        others = operand_refs[1][0].astype(jnp.float32)
-        for ref in operand_refs[2:]:
-            others = others * ref[0].astype(jnp.float32)
+        others = operand(1)
+        for i in range(2, num_operands):
+            others = others * operand(i)
         prod = prod * others
     # collisions inside the block are summed by the MXU
     local = rows_ref[0, 0] - tile * row_tile  # (BLOCK,), in [0, row_tile)
@@ -147,24 +173,38 @@ def mttkrp_pallas_call(
     interpret: bool,
     carry: Optional[Array] = None,  # (num_row_tiles * row_tile, RP) float32
                                     #  output of an earlier call, aliased
+    slots: Optional[Sequence[Optional[Array]]] = None,
+                        # per operand, None or (nblocks, 1, BLOCK) int32:
+                        #  the slot of each row of a packed operand
+    slot_width: Optional[int] = None,  # the lanes of a slot: the rank
 ) -> Array:
     """``out[r] = sum over the non-zeros of row r of vals * op0 * op1 ...``,
     a ``(num_row_tiles * row_tile, RP)`` float32 array.  Without ``carry``
     every visited tile starts from zeros; with it the output is ``carry``'s
     buffer, each visited tile starts from its carried value and the tiles
-    not visited are ``carry``'s."""
+    not visited are ``carry``'s.
+
+    An operand with a slot stream is packed: each of its rows holds
+    ``RP // slot_width`` slots of ``slot_width`` lanes, and its factor row
+    is slot ``slots[i]``.  The product is formed from that slot, moved to
+    lanes ``[0, slot_width)``; the output's lanes from ``slot_width`` on
+    then hold sums of other slots' products, for the caller to slice off."""
     nblocks, _, block = rows.shape
     rp = operands[0].shape[-1]
     if rp % LANE:
         raise ValueError(f"rank must be padded to {LANE}, got {rp}")
+    slots = [None] * len(operands) if slots is None else list(slots)
+    packed = tuple(slot is not None for slot in slots)
 
     # (1, 1, block): the last two block dims equal the array's (1, block),
     # which the TPU lowering requires of a 1-row block
     index_spec = pl.BlockSpec((1, 1, block), lambda b, tm: (b, 0, 0))
     operand_spec = pl.BlockSpec((1, block, rp), lambda b, tm: (b, 0, 0))
     tile_spec = pl.BlockSpec((row_tile, rp), lambda b, tm: (tm[b], 0))
-    in_specs = [index_spec, index_spec] + [operand_spec] * len(operands)
-    args = [block_tile, rows, vals, *operands]
+    slot_streams = [slot for slot in slots if slot is not None]
+    in_specs = ([index_spec, index_spec] + [operand_spec] * len(operands)
+                + [index_spec] * len(slot_streams))
+    args = [block_tile, rows, vals, *operands, *slot_streams]
     aliases = {}
     if carry is not None:
         in_specs.append(tile_spec)
@@ -177,8 +217,8 @@ def mttkrp_pallas_call(
         out_specs=tile_spec,
     )
     return pl.pallas_call(
-        functools.partial(_kernel, row_tile=row_tile,
-                          num_operands=len(operands),
+        functools.partial(_kernel, row_tile=row_tile, packed=packed,
+                          slot_width=slot_width,
                           carried=carry is not None),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((num_row_tiles * row_tile, rp), jnp.float32),

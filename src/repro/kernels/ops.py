@@ -18,6 +18,7 @@ import jax.numpy as jnp
 
 from repro.core.csf import CSF
 from repro.core.linearized import Linearized
+from repro.obs.metrics import get_registry
 
 from .linearized_pallas import mttkrp_lin_pallas_call
 from .mttkrp_pallas import LANE, mttkrp_pallas_call
@@ -58,6 +59,35 @@ def _padded(factor: Array) -> Array:
     return jax.lax.optimization_barrier(_pad_lanes(factor))
 
 
+# The most bytes a factor's lane-padded table may take for XLA to keep it in
+# VMEM beside everything else a sweep holds there (a v5e core has 128 MiB).
+# A factor whose padded table is larger is gathered from a table packed
+# ``LANE // rank`` rows to a lane row (``_slots_per_row``).
+VMEM_TABLE_BYTES = 64 * 2**20
+
+
+def _slots_per_row(factor: Array) -> int:
+    """How many of ``factor``'s rows share a lane row of the table its rows
+    are gathered from: ``LANE // rank`` where its lane-padded table exceeds
+    ``VMEM_TABLE_BYTES`` and at least two rows fit a lane row, else 1 (the
+    lane-padded table, ``_padded``)."""
+    rows, rank = factor.shape
+    k = LANE // rank
+    if k >= 2 and rows * LANE * factor.dtype.itemsize > VMEM_TABLE_BYTES:
+        return k
+    return 1
+
+
+def _packed(factor: Array, k: int) -> Array:
+    """``factor``'s rows packed ``k`` to a lane row: row ``w`` in lane row
+    ``w // k``, lanes ``[w % k * R, w % k * R + R)``, every other lane zero.
+    Behind the same barrier as ``_padded``."""
+    rows, rank = factor.shape
+    n = -(-rows // k)
+    table = jnp.pad(factor, ((0, n * k - rows), (0, 0))).reshape(n, k * rank)
+    return jax.lax.optimization_barrier(_pad_lanes(table))
+
+
 def _gather_padded(factor: Array, ids: Array) -> Array:
     """Rows ``factor[ids]`` padded to the lane width, gathered from the
     padded factor (``_padded``)."""
@@ -88,6 +118,13 @@ def mttkrp(csf: CSF, factors: Sequence[Array], *,
     ``gather/khatri_rao``, and the mode's blocks go through the kernel in
     consecutive ranges (``_block_ranges``), the output carried from call to
     call: each range's gathered rows are live only for its own call.
+
+    A factor too large for its lane-padded table to stay in VMEM
+    (``_slots_per_row``) is gathered, under the scope ``packed``, from a
+    table packed ``k`` rows to a lane row at ``ids // k``, and the kernel
+    moves slot ``ids % k`` of each gathered row to lanes ``[0, R)``.  The
+    gauge ``mttkrp.packed_gathers.mode{n}`` holds, once mode n is traced,
+    how many of its gathered operands are read from a packed table.
     """
     if interpret is None:
         interpret = default_interpret()
@@ -96,20 +133,33 @@ def mttkrp(csf: CSF, factors: Sequence[Array], *,
     nblocks, block = csf.num_blocks, csf.block
     ranges = _block_ranges(nblocks, csf.order)
     whole = len(ranges) == 1
-    padded = {}  # each factor lane-padded once a mode, on its first gather
+    slots_per_row = [_slots_per_row(factors[m]) for m in om]
+    get_registry().gauge(f"mttkrp.packed_gathers.mode{csf.mode}").set(
+        sum(k > 1 for k in slots_per_row))
+    tables = {}  # each factor's table built once a mode, on its first gather
 
-    def gather(i: int, ids: Array) -> Array:
-        if i not in padded:
-            padded[i] = _padded(factors[om[i]])
-        return padded[i][ids[:, i]]
+    def gather(i: int, ids: Array) -> tuple[Array, Optional[Array]]:
+        """Operand ``i``'s gathered rows and, read from a packed table,
+        the slot of each, ``(nblocks, 1, block)``."""
+        k = slots_per_row[i]
+        if k == 1:
+            if i not in tables:
+                tables[i] = _padded(factors[om[i]])
+            return tables[i][ids[:, i]], None
+        with jax.named_scope("packed"):
+            if i not in tables:
+                tables[i] = _packed(factors[om[i]], k)
+            return (tables[i][ids[:, i] // k],
+                    (ids[:, i] % k).reshape(-1, 1, block))
 
     out = None
     for lo, hi in ranges:
         ids = csf.other_ids if whole else csf.other_ids[lo * block:hi * block]
         with jax.named_scope("gather"):
-            operands = [gather(i, ids) for i in range(2)]
+            gathered = [gather(i, ids) for i in range(2)]
             with jax.named_scope("khatri_rao"):
-                operands += [gather(i, ids) for i in range(2, len(om))]
+                gathered += [gather(i, ids) for i in range(2, len(om))]
+        operands, slots = zip(*gathered)
         rp = operands[0].shape[-1]
         if out is None and not whole:
             with jax.named_scope("kernel"):
@@ -131,6 +181,8 @@ def mttkrp(csf: CSF, factors: Sequence[Array], *,
                 row_tile=csf.row_tile,
                 interpret=interpret,
                 carry=out,
+                slots=slots,
+                slot_width=rank,
             )
     return out[: csf.num_rows, :rank].astype(factors[0].dtype)
 

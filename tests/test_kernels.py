@@ -1,6 +1,8 @@
 """Per-kernel allclose sweeps: Pallas (interpret=True) vs pure-jnp oracle,
 across shapes / ranks / dtypes / block sizes, plus CP-ALS integration."""
 import dataclasses
+import json
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -8,9 +10,11 @@ import numpy as np
 import pytest
 
 from repro.core import random_sparse, build_csf_tiled, init_factors, cp_als, mttkrp
+from repro.core.csf import CSF
 from repro.kernels import mttkrp_pallas, ops, ref
 from repro.kernels.linearized_pallas import mttkrp_lin_pallas_call
 from repro.kernels.mttkrp_pallas import mttkrp_pallas_call
+from repro.obs.metrics import scoped_registry
 
 KEY = jax.random.PRNGKey(7)
 
@@ -218,6 +222,133 @@ def test_kernel_call_adds_onto_its_carry():
     kept = np.repeat(~visited, csf.row_tile)
     np.testing.assert_array_equal(np.asarray(got)[kept],
                                   np.asarray(carry)[kept])
+
+
+# ---------------------------------------------------------------------------
+# a factor too large for VMEM gathered from a table packed k rows a lane row
+# ---------------------------------------------------------------------------
+
+def _padded_bytes(factor) -> int:
+    return factor.shape[0] * mttkrp_pallas.LANE * factor.dtype.itemsize
+
+
+def _mttkrp_packing_above(csf, factors, table_bytes, monkeypatch):
+    """``ops.mttkrp`` traced anew with ``VMEM_TABLE_BYTES`` at
+    ``table_bytes``, and the gauge of how many of the mode's gathered
+    operands it read from a packed table.  The jit caches are cleared
+    before and after, so no other test meets this trace."""
+    monkeypatch.setattr(ops, "VMEM_TABLE_BYTES", table_bytes)
+    jax.clear_caches()
+    try:
+        with scoped_registry() as registry:
+            got = np.asarray(ops.mttkrp(csf, factors))
+        gauge = registry.gauge(f"mttkrp.packed_gathers.mode{csf.mode}")
+        return got, gauge.value
+    finally:
+        jax.clear_caches()
+
+
+@pytest.mark.parametrize("dims,nnz,mode,rank,packed", [
+    # order 3, both gathered operands packed: 3 and 2 rows a lane row, row
+    # counts that are not multiples of 3 or 2
+    ((50, 40, 301), 2000, 0, 35, (1, 2)),
+    ((50, 41, 301), 2000, 2, 64, (0, 1)),
+    # order 4 in two block ranges, the carry across them; the long factor
+    # in the gather position (mode 0), in the khatri_rao position (mode 3)
+    ((40, 15, 2000, 10), 3000, 0, 35, (2,)),
+    ((40, 15, 2000, 10), 3000, 3, 35, (2,)),
+    ((40, 15, 2000, 10), 3000, 3, 64, (2,)),
+    ((40, 15, 2000, 10), 3000, 1, 35, (0, 2, 3)),
+    # rank 65: one row fills more than half a lane row, nothing packed
+    ((50, 40, 301), 2000, 0, 65, ()),
+])
+def test_packed_gathers_give_the_padded_gathers_bits(dims, nnz, mode, rank,
+                                                     packed, monkeypatch):
+    """A factor whose lane-padded table is over ``VMEM_TABLE_BYTES`` is
+    gathered from a table packed ``LANE // rank`` rows a lane row, and the
+    kernel moves each row's slot to lanes ``[0, R)``: the MTTKRP is the
+    one over the lane-padded tables to the last bit, in the gather and the
+    khatri_rao positions and across an order-4 mode's two block ranges."""
+    t, csfs, factors = make_case(dims, nnz, rank=rank, skew=1.0, block=64,
+                                 row_tile=8)
+    csf = csfs[mode]
+    if t.order > 3:
+        assert len(ops._block_ranges(csf.num_blocks, t.order)) == 2
+    want, none = _mttkrp_packing_above(csf, factors, ops.VMEM_TABLE_BYTES,
+                                       monkeypatch)
+    assert none == 0
+    # a table just under the smallest packed factor's: those pack, the
+    # rest keep the padded table
+    unpacked = [_padded_bytes(factors[m]) for m in csf.other_modes
+                if m not in packed]
+    limit = min([_padded_bytes(factors[m]) for m in packed] or [0]) - 1
+    assert all(b <= limit for b in unpacked) or not packed
+    got, count = _mttkrp_packing_above(csf, factors, max(limit, 0),
+                                       monkeypatch)
+    assert count == len(packed)
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(
+        want, _summed_as_the_kernel_sums(csf, factors)[:csf.num_rows, :rank])
+
+
+@pytest.mark.parametrize("rows,rank,k", [(301, 35, 3), (300, 35, 3),
+                                         (7, 64, 2), (1, 42, 3)])
+def test_packed_table_holds_row_w_in_slot_w_mod_k(rows, rank, k):
+    factor = jax.random.normal(KEY, (rows, rank))
+    table = np.asarray(ops._packed(factor, k))
+    assert table.shape == (-(-rows // k), mttkrp_pallas.LANE)
+    for w in range(rows):
+        lanes = slice(w % k * rank, w % k * rank + rank)
+        np.testing.assert_array_equal(table[w // k, lanes],
+                                      np.asarray(factor[w]))
+    in_slots = np.zeros_like(table, bool)
+    for w in range(rows):
+        in_slots[w // k, w % k * rank:w % k * rank + rank] = True
+    assert not table[~in_slots].any()
+
+
+CONFIGS = Path(__file__).resolve().parent.parent / "chipbench" / "configs"
+
+
+@pytest.mark.parametrize("config,counts", [("yelp", [0, 0, 0]),
+                                           ("nell-2", [0, 0, 0]),
+                                           ("enron", [1, 1, 0, 1])])
+def test_packed_gathers_gauge_at_the_benchmark_shapes(config, counts):
+    """Traced at a benchmark configuration's dims and rank, each mode sets
+    ``mttkrp.packed_gathers.mode{n}`` to how many of its gathered operands
+    come from a packed table: only enron's 244,268-row word factor is
+    over the limit, and every mode but its own gathers it."""
+    cfg = json.loads((CONFIGS / f"{config}.json").read_text())
+    dims, rank, block = tuple(cfg["dims"]), cfg["rank"], 512
+    factors = [jax.ShapeDtypeStruct((d, rank), jnp.float32) for d in dims]
+    got = []
+    for mode in range(len(dims)):
+        nnz = 4 * block
+        csf = CSF(mode=mode,
+                  row_ids=jax.ShapeDtypeStruct((nnz,), jnp.int32),
+                  other_ids=jax.ShapeDtypeStruct((nnz, len(dims) - 1),
+                                                 jnp.int32),
+                  vals=jax.ShapeDtypeStruct((nnz,), jnp.float32),
+                  block_tile=jax.ShapeDtypeStruct((4,), jnp.int32),
+                  dims=dims, nnz=nnz, block=block, row_tile=128)
+        jax.clear_caches()
+        with scoped_registry() as registry:
+            jax.eval_shape(ops.mttkrp, csf, factors)
+        got.append(registry.gauge(f"mttkrp.packed_gathers.mode{mode}").value)
+    jax.clear_caches()
+    assert got == counts
+
+
+@pytest.mark.parametrize("rows,rank,k", [
+    (100, 35, 1),         # a padded table under the limit
+    (140_000, 35, 3),     # 71.7 MB padded
+    (140_000, 64, 2),
+    (140_000, 65, 1),     # two rows do not fit a lane row
+    (140_000, 128, 1),
+])
+def test_slots_per_row_from_padded_bytes_and_rank(rows, rank, k):
+    factor = jax.ShapeDtypeStruct((rows, rank), jnp.float32)
+    assert ops._slots_per_row(factor) == k
 
 
 # ---------------------------------------------------------------------------

@@ -210,6 +210,47 @@ def test_fused_sweep_multiplies_every_operand_in_the_kernel(
         assert math.prod(lead) <= max(dims), shape_text
 
 
+_DEF = re.compile(r"^\s*(?:ROOT\s+)?%([\w.\-]+) = (\w+\[[\d,]*\]\{[^}]*\})")
+_GATHER = re.compile(r"^\s*%[\w.\-]+ = f32\[\d+,128\]\S* fusion\(%([\w.\-]+),"
+                     r".*op_name=\"([^\"]*/gather)\"")
+
+
+def _gather_tables(text: str) -> list[tuple[str, str]]:
+    """Each gather fusion of a compiled program: its ``op_name`` and the
+    shape, layout and memory space of the table it reads (``S(1)`` is
+    VMEM)."""
+    defs = {}
+    for line in text.splitlines():
+        m = _DEF.match(line)
+        if m:
+            defs.setdefault(m.group(1), m.group(2))
+    return [(m.group(2), defs[m.group(1)])
+            for m in map(_GATHER.match, text.splitlines()) if m]
+
+
+@pytest.mark.parametrize("shape,gathers,packed", [("yelp", 6, 0),
+                                                  ("enron", 24, 6)])
+def test_fused_sweep_gathers_an_oversized_factor_from_a_packed_table(
+        fused_sweep, shape, gathers, packed):
+    """At enron's shape the 244,268-row word factor, whose lane-padded table
+    (125 MB) is over ``ops.VMEM_TABLE_BYTES``, is gathered in modes 0, 1 and
+    3 (two block ranges each) from a table of three rank-35 rows a lane
+    row, ``f32[81423,128]``, which XLA places in VMEM for at least one of
+    them, and no gather reads its padded table.  At yelp's shape every
+    padded table is under the limit: nothing is packed, and the longest
+    factor's rows are gathered from its padded table."""
+    dims = SWEEPS[shape][0]
+    tables = _gather_tables(fused_sweep(shape).as_text())
+    assert len(tables) == gathers
+    read = [table for op, table in tables if "/packed/" in op]
+    assert len(read) == packed
+    rows = -(-max(dims) // (LANE // RANK))
+    assert all(table.startswith(f"f32[{rows},128]") for table in read)
+    assert any("S(1)" in table for table in read) == bool(packed)
+    padded = f"f32[{max(dims)},128]"
+    assert any(t.startswith(padded) for _, t in tables) == (not packed)
+
+
 def test_kernel_keeps_its_name_under_its_scope(one_chip):
     """The scope that names the MTTKRP's reduction (``.../kernel/...``)
     leaves the custom call named after the kernel, as a trace and a
